@@ -118,6 +118,8 @@ def main() -> None:
                     help="write BENCH_<name>.json per bench here")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     from benchmarks import (bench_ablations, bench_control, bench_fig2,
                             bench_kernels, bench_population,
                             bench_scheduling, bench_table1, roofline)

@@ -178,6 +178,9 @@ class Simulator:
         # runs each group's [cut, L) suffix over a concatenated batch)
         self._srv_step_batched = splitfl.make_server_step_cls_batched(
             self.model, self.opt, impl=run.engine.cohort_impl)
+        # held-out logits; built once so every evaluate() reuses one trace
+        self._eval_logits = jax.jit(
+            lambda p, lo, b: self.model.loss(p, lo, b, path="scan")[1])
         self._last_event = None   # EngineResult of the last event-driven round
 
         # analytic per-step Eq.10 terms (fixed per client); wireless terms
@@ -980,11 +983,11 @@ class Simulator:
 
         preds, golds = [], []
         loader = ClassificationLoader(self.test, self.run.batch_size, seed=0)
-        fn = jax.jit(lambda p, lo, b: self.model.loss(p, lo, b, path="scan")[1])
         for i, batch in enumerate(loader.all_batches()):
             if i >= max_batches:
                 break
-            logits = fn(params, full, {k: jnp.asarray(v) for k, v in batch.items()})
+            logits = self._eval_logits(
+                params, full, {k: jnp.asarray(v) for k, v in batch.items()})
             preds.append(np.argmax(np.asarray(logits), -1))
             golds.append(batch["label"])
         pred = np.concatenate(preds)

@@ -3,9 +3,11 @@ the production mesh, extract memory/cost/roofline terms. No allocation —
 inputs are ShapeDtypeStructs; the 512 host devices below are placeholders
 for GSPMD partitioning only.
 """
-# The VERY FIRST two lines — before ANY other import (jax locks the device
-# count on first init):
+# The VERY FIRST lines — before ANY other import (jax locks the platform and
+# the device count on first init).  The placeholder devices are host (CPU)
+# devices, so the tool never asks for an attached accelerator:
 import os
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            + os.environ.get("XLA_FLAGS", ""))
 

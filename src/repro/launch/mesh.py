@@ -9,16 +9,24 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all Auto: the sharding rules in sharding.py
+    place arrays with ``with_sharding_constraint``, which Explicit axes
+    (``jax.make_mesh``'s default) refuse."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """TPU v5e: 16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 4):
     """Small mesh for in-test dry-runs (requires forced host devices)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def dp_axes(mesh) -> tuple:

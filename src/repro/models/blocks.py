@@ -24,16 +24,6 @@ from repro.models import layers as L
 
 Array = jax.Array
 
-# jax >= 0.6 exposes shard_map at the top level with ``check_vma``; older
-# releases ship it under jax.experimental with ``check_rep``.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
-
-
 # ===========================================================================
 # dense attention block (also the MoE attention half and zamba's shared blk)
 # ===========================================================================
@@ -302,11 +292,11 @@ def moe_mlp_sharded(cfg: ModelConfig, p: dict, lora, x: Array, ctx: dict):
 
     batch_ok = b % math.prod(mesh.shape[a] for a in dp) == 0
     x_spec = P(dp if batch_ok else None, None, None)
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(x_spec, p_specs, l_specs),
         out_specs=(x_spec, P()),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )(x, moe_p, moe_lora)
     return out, aux
 

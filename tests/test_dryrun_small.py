@@ -23,7 +23,8 @@ cfg = reduced(get_config(arch), n_layers=2, d_model=256)
 shape = get_shape(shape_name)
 import dataclasses
 shape = dataclasses.replace(shape, seq_len=64, global_batch=8)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 bundle = build_step(cfg, shape, mesh, ShardingPolicy())
 lowered = bundle.lower()
 compiled = lowered.compile()
@@ -43,6 +44,7 @@ print(json.dumps({
 def _run(arch, shape):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"   # host devices only; never the chip
     out = subprocess.run(
         [sys.executable, "-c", SCRIPT % {"arch": arch, "shape": shape}],
         capture_output=True, text=True, env=env, timeout=420)

@@ -51,6 +51,7 @@ print(json.dumps({"ref": float(loss_ref), "sm": float(loss_sm),
 def test_moe_shard_map_equivalence():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"   # host devices only; never the chip
     out = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,
                          text=True, env=env, timeout=420)
     assert out.returncode == 0, out.stderr[-3000:]
