@@ -79,7 +79,7 @@ def make_server_step(model, opt: AdamW, *, path: str = "sliced",
         return loss, new_lora, new_opt, g_v
 
     if static_cut is not None:
-        step = functools.partial(step, cut=static_cut)
+        step = _bind_cut(step, static_cut)
         return jax.jit(step, donate_argnums=(1, 2) if donate else ())
     return jax.jit(step, donate_argnums=(1, 2) if donate else ())
 
@@ -108,8 +108,17 @@ def make_server_step_cls(model, opt: AdamW, *, path: str = "sliced",
         return loss, new_tr["lora"], new_tr["head"], new_opt, g_v
 
     if static_cut is not None:
-        step = functools.partial(step, cut=static_cut)
+        step = _bind_cut(step, static_cut)
     return jax.jit(step)
+
+
+def _bind_cut(step, cut: int):
+    """``step`` with its ``cut`` bound, under its own name: the profiler
+    then names the program ``jit_step`` (a bare ``functools.partial`` would
+    read ``jit__unknown``)."""
+    bound = functools.partial(step, cut=cut)
+    bound.__name__ = step.__name__
+    return bound
 
 
 def _chunk_slices(u: int, cohort_chunk: Optional[int]):

@@ -49,6 +49,7 @@ from repro.fed.engine import (AGG_POLICIES, ClockConfig, FederationClock,
                               RoundPlan, jobs_from_times)
 from repro.net.topology import EdgeTopology
 from repro.models import build_model
+from repro.obs.host import span
 from repro.optim import AdamW
 
 SFL_FRAGMENTATION = 1.04   # multi-model GPU contention overhead (paper §V-B)
@@ -245,6 +246,8 @@ class Simulator:
         self._round_pull: dict = {}
         self._client_version = [0] * self.u
         self.discarded_updates: List[tuple] = []   # (uid, round)
+        # the round (or rounds) being served: the host spans' ``round`` id
+        self._serve_round: Union[int, str, None] = None
         # mid-flight checkpoint/resume plumbing (docs/checkpointing.md):
         # the periodic snapshotter rides the clock's tick callback, a
         # loaded clock snapshot waits here until _run_event builds the
@@ -419,20 +422,22 @@ class Simulator:
         if run.engine.mode == "event":
             raise RuntimeError("engine='event' rounds are owned by the "
                                "FederationClock; call run_training()")
-        self._times_this_round = self._adjusted_times()
-        self._sample_cohort()
-        if run.scheme == "sl":
-            losses, order = self._round_sl()
-        else:
-            losses, order = self._round_parallel()
-        self.sim_clock += self._round_time(order)
+        with span("fed.round", round=rnd):
+            self._serve_round = rnd
+            self._times_this_round = self._adjusted_times()
+            self._sample_cohort()
+            if run.scheme == "sl":
+                losses, order = self._round_sl()
+            else:
+                losses, order = self._round_parallel()
+            self.sim_clock += self._round_time(order)
 
-        # aggregation phase (not for SL)
-        if run.scheme in ("ours", "sfl") and (rnd + 1) % run.agg.interval == 0:
-            self.sim_clock += self._commit_sync(None)
+            # aggregation phase (not for SL)
+            if run.scheme in ("ours", "sfl") and (rnd + 1) % run.agg.interval == 0:
+                self.sim_clock += self._commit_sync(None)
 
-        # a deadline can cut every client out of a round -> no losses
-        mean_loss = float(np.mean(losses)) if losses else float("nan")
+            # a deadline can cut every client out of a round -> no losses
+            mean_loss = float(np.mean(losses)) if losses else float("nan")
         rec = RoundRecord(rnd, self.sim_clock, mean_loss)
         self.history.append(rec)
         return rec
@@ -456,48 +461,65 @@ class Simulator:
         batch draw + client forward (with optional int8+EF uplink), then the
         sequential server step (size-1 group) or ONE batched vmapped dispatch
         (size>1), then each client's backward.  Shared by the analytic round
-        body and the FederationClock's serve events."""
+        body and the FederationClock's serve events.
+
+        The host span ``fed.serve`` covers the group, from the batch draw to
+        the last backward dispatch (``repro.obs.host``)."""
+        ids = ({"uid": grp[0]} if len(grp) == 1
+               else {"uids": " ".join(map(str, grp))})
+        with span("fed.serve", round=self._serve_round, **ids):
+            return self._serve_group_body(grp)
+
+    def _serve_group_body(self, grp: List[int]) -> List[float]:
         run = self.run
         batches, acts = {}, {}
         for u in grp:
-            batch = {k: jnp.asarray(v)
-                     for k, v in self.loaders[u].next_batch().items()}
-            batches[u] = batch
-            fwd, _ = self._cli_steps[self.cuts[u]]
-            v = fwd(self.client_params[u], self.client_lora[u], batch)
-            if run.net.quantize:
-                # int8 + error-feedback uplink (repro/comm)
-                from repro.comm import dequantize, quantize_with_feedback
-                qx, self._ef_residual[u] = quantize_with_feedback(
-                    v, self._ef_residual[u])
-                v = dequantize(qx, v.dtype)
-            acts[u] = v
+            with span("fed.client_fwd", uid=u):
+                batch = {k: jnp.asarray(v)
+                         for k, v in self.loaders[u].next_batch().items()}
+                batches[u] = batch
+                fwd, _ = self._cli_steps[self.cuts[u]]
+                v = fwd(self.client_params[u], self.client_lora[u], batch)
+                if run.net.quantize:
+                    # int8 + error-feedback uplink (repro/comm)
+                    from repro.comm import dequantize, quantize_with_feedback
+                    qx, self._ef_residual[u] = quantize_with_feedback(
+                        v, self._ef_residual[u])
+                    v = dequantize(qx, v.dtype)
+                acts[u] = v
 
         losses: List[float] = []
         if len(grp) == 1:
             u = grp[0]
             cut = self.cuts[u]
-            loss, new_lora, new_head, new_opt, dv = self._srv_steps[cut](
-                self.params, self.server_lora[u], self.heads[u],
-                self.server_opt[u], acts[u], batches[u])
-            losses.append(float(loss))
-            self._apply_server_update(u, new_lora, new_head, new_opt)
-            self._client_backward(u, batches[u], dv)
+            with span("fed.server_step", cut=cut):
+                loss, new_lora, new_head, new_opt, dv = self._srv_steps[cut](
+                    self.params, self.server_lora[u], self.heads[u],
+                    self.server_opt[u], acts[u], batches[u])
+            with span("fed.loss_read"):
+                losses.append(float(loss))
+            with span("fed.client_bwd", uid=u):
+                self._apply_server_update(u, new_lora, new_head, new_opt)
+                self._client_backward(u, batches[u], dv)
             return losses
         # batched cohort chunk: one vmapped dispatch for the whole group
-        loss_g, nl, nh, no, dv_g = self._srv_step_batched(
-            self.params,
-            lora_lib.stack_trees([self.server_lora[u] for u in grp]),
-            jnp.stack([self.heads[u] for u in grp]),
-            lora_lib.stack_trees([self.server_opt[u] for u in grp]),
-            jnp.stack([acts[u] for u in grp]),
-            lora_lib.stack_trees([batches[u] for u in grp]),
-            jnp.asarray([self.cuts[u] for u in grp]))
-        nls, nos = lora_lib.unstack_tree(nl), lora_lib.unstack_tree(no)
+        with span("fed.server_step",
+                  cut=" ".join(str(self.cuts[u]) for u in grp)):
+            loss_g, nl, nh, no, dv_g = self._srv_step_batched(
+                self.params,
+                lora_lib.stack_trees([self.server_lora[u] for u in grp]),
+                jnp.stack([self.heads[u] for u in grp]),
+                lora_lib.stack_trees([self.server_opt[u] for u in grp]),
+                jnp.stack([acts[u] for u in grp]),
+                lora_lib.stack_trees([batches[u] for u in grp]),
+                jnp.asarray([self.cuts[u] for u in grp]))
+            nls, nos = lora_lib.unstack_tree(nl), lora_lib.unstack_tree(no)
         for i, u in enumerate(grp):
-            losses.append(float(loss_g[i]))
-            self._apply_server_update(u, nls[i], nh[i], nos[i])
-            self._client_backward(u, batches[u], dv_g[i])
+            with span("fed.loss_read"):
+                losses.append(float(loss_g[i]))
+            with span("fed.client_bwd", uid=u):
+                self._apply_server_update(u, nls[i], nh[i], nos[i])
+                self._client_backward(u, batches[u], dv_g[i])
         return losses
 
     def _apply_server_update(self, u: int, new_lora, new_head, new_opt):
@@ -685,6 +707,9 @@ class Simulator:
                 swapped[u] = (r, pull[2], self.client_lora[u],
                               self.client_opt[u])
                 self.client_lora[u], self.client_opt[u] = pull[0], pull[1]
+        rounds = sorted(set(ev.rounds))
+        self._serve_round = (rounds[0] if len(rounds) == 1
+                             else " ".join(map(str, rounds)))
         losses = self._serve_group(list(ev.uids))
         for u, (r, pull_version, cur_lora, cur_opt) in swapped.items():
             if self._client_version[u] != pull_version:
@@ -741,59 +766,69 @@ class Simulator:
         aggregate is computed under the OLD cuts (that is what the clients
         trained), then cuts may move, then the aggregate is redistributed
         re-split at the NEW cuts."""
-        servers_split = [lora_lib.split_lora(self.server_lora[u],
-                                             self.cuts[u])[1]
+        ids = ({"round": self._serve_round} if ev is None
+               else {"version": ev.version})
+        with span("fed.commit", clients=self.u, **ids):
+            return self._commit_sync_body(ev)
+
+    def _commit_sync_body(self, ev) -> Union[float, Dict[int, float]]:
+        with span("commit.aggregate"):
+            servers_split = [lora_lib.split_lora(self.server_lora[u],
+                                                 self.cuts[u])[1]
+                             for u in range(self.u)]
+            if self._edges is not None:
+                # two-tier Eq. 6-8: edge cells partially merge their members,
+                # the cloud merges the edge summaries (telescopes to the flat
+                # weighted mean; edge partials kept for inspection/tests)
+                fulls = [lora_lib.assemble_full(self.client_lora[u],
+                                                servers_split[u], self.cuts[u])
                          for u in range(self.u)]
-        if self._edges is not None:
-            # two-tier Eq. 6-8: edge cells partially merge their members,
-            # the cloud merges the edge summaries (telescopes to the flat
-            # weighted mean; edge partials kept for inspection/tests)
-            fulls = [lora_lib.assemble_full(self.client_lora[u],
-                                            servers_split[u], self.cuts[u])
-                     for u in range(self.u)]
-            agg_full, self.edge_summaries, self.edge_masses = \
-                agg_lib.hierarchical_aggregate(
-                    fulls, [float(s) for s in self.data_sizes],
-                    [list(cell) for cell in self._edges.cells])
-            new_c, new_s = [], []
-            for cut in self.cuts:
-                c, s = lora_lib.split_lora(agg_full, cut)
-                new_c.append(c)
-                new_s.append(s)
-        else:
-            new_c, new_s, agg_full = agg_lib.aggregation_round(
-                self.client_lora, servers_split, self.cuts, self.data_sizes)
+                agg_full, self.edge_summaries, self.edge_masses = \
+                    agg_lib.hierarchical_aggregate(
+                        fulls, [float(s) for s in self.data_sizes],
+                        [list(cell) for cell in self._edges.cells])
+                new_c, new_s = [], []
+                for cut in self.cuts:
+                    c, s = lora_lib.split_lora(agg_full, cut)
+                    new_c.append(c)
+                    new_s.append(s)
+            else:
+                new_c, new_s, agg_full = agg_lib.aggregation_round(
+                    self.client_lora, servers_split, self.cuts, self.data_sizes)
         # the UPLOAD leg shipped the adapters the clients actually trained —
         # price it at the PRE-migration cuts, before any decision applies
         up_old = max(self.link.transfer_s(lora_upload_bytes(self.cfg, cut))
                      for cut in self.cuts)
         mig: Dict[int, float] = {}
         changes: Dict[int, Tuple[int, int]] = {}
-        if self._control is not None and ev is not None:
-            changes, mig = self._control.decide(ev.time,
-                                                list(range(self.u)),
-                                                ev.version)
-            if changes:
-                self._apply_cut_changes(changes)
-                for u in changes:     # re-split the aggregate at the new cut
-                    new_c[u], new_s[u] = lora_lib.split_lora(agg_full,
-                                                             self.cuts[u])
-        self.client_lora = new_c
-        self.server_lora = [
-            lora_lib.embed_in_full_shape(s, self.lora_spec, cut, "server")
-            for s, cut in zip(new_s, self.cuts)]
-        # heads: dataset-weighted FedAvg
-        w = np.array(self.data_sizes, np.float64)
-        w /= w.sum()
-        head = jax.tree.map(
-            lambda *hs: sum(float(wi) * h for wi, h in zip(w, hs)),
-            *self.heads)
-        self.heads = [head] * self.u
+        with span("commit.redistribute"):
+            if self._control is not None and ev is not None:
+                changes, mig = self._control.decide(ev.time,
+                                                    list(range(self.u)),
+                                                    ev.version)
+                if changes:
+                    self._apply_cut_changes(changes)
+                    for u in changes:     # re-split the aggregate at the new cut
+                        new_c[u], new_s[u] = lora_lib.split_lora(agg_full,
+                                                                 self.cuts[u])
+            self.client_lora = new_c
+            self.server_lora = [
+                lora_lib.embed_in_full_shape(s, self.lora_spec, cut, "server")
+                for s, cut in zip(new_s, self.cuts)]
+        with span("commit.heads"):
+            # heads: dataset-weighted FedAvg
+            w = np.array(self.data_sizes, np.float64)
+            w /= w.sum()
+            head = jax.tree.map(
+                lambda *hs: sum(float(wi) * h for wi, h in zip(w, hs)),
+                *self.heads)
+            self.heads = [head] * self.u
         self._global_full, self._global_head = agg_full, head
-        # optimizer states reset to match redistributed adapters
-        self.client_opt = [self.opt.init(c) for c in self.client_lora]
-        self.server_opt = [self.opt.init({"lora": s, "head": self.heads[u]})
-                           for u, s in enumerate(self.server_lora)]
+        with span("commit.opt_reset"):
+            # optimizer states reset to match redistributed adapters
+            self.client_opt = [self.opt.init(c) for c in self.client_lora]
+            self.server_opt = [self.opt.init({"lora": s, "head": self.heads[u]})
+                               for u, s in enumerate(self.server_lora)]
         if self.run.agg.transport == "plane":
             if ev is not None:
                 # the clock ships the adapters through the plane (two-tier
@@ -837,13 +872,13 @@ class Simulator:
         the absent data mass on the current global, and redistribute to the
         contributors only (they re-enter at the new version; the rest keep
         training until their own next commit)."""
+        with span("fed.commit", version=ev.version,
+                  clients=len(ev.contributors)):
+            return self._commit_async_body(ev, verbose)
+
+    def _commit_async_body(self, ev, verbose: bool) -> float:
         run = self.run
         contribs = list(ev.contributors)
-        fulls = [lora_lib.assemble_full(
-                     self.client_lora[u],
-                     lora_lib.split_lora(self.server_lora[u], self.cuts[u])[1],
-                     self.cuts[u])
-                 for u in contribs]
         alpha = 0.0
         if run.agg.policy == "staleness":
             alpha = 0.5 if run.agg.staleness_alpha is None else run.agg.staleness_alpha
@@ -851,11 +886,18 @@ class Simulator:
              for u, s in zip(contribs, ev.staleness)]
         anchor = float(sum(self.data_sizes)
                        - sum(self.data_sizes[u] for u in contribs))
-        self._global_full = agg_lib.merge_into_global(
-            self._global_full, fulls, w, anchor)
-        self._global_head = agg_lib.aggregate_full_weighted(
-            [self._global_head] + [self.heads[u] for u in contribs],
-            [anchor] + w)
+        with span("commit.aggregate"):
+            fulls = [lora_lib.assemble_full(
+                         self.client_lora[u],
+                         lora_lib.split_lora(self.server_lora[u], self.cuts[u])[1],
+                         self.cuts[u])
+                     for u in contribs]
+            self._global_full = agg_lib.merge_into_global(
+                self._global_full, fulls, w, anchor)
+        with span("commit.heads"):
+            self._global_head = agg_lib.aggregate_full_weighted(
+                [self._global_head] + [self.heads[u] for u in contribs],
+                [anchor] + w)
         # control decision: contributors stand at this commit boundary, but
         # only those with NO in-flight local round may migrate (an in-flight
         # round pulled client state shaped by the old cut).  The upload leg
@@ -865,23 +907,26 @@ class Simulator:
                      for u in contribs)
         mig: Dict[int, float] = {}
         changes: Dict[int, Tuple[int, int]] = {}
-        if self._control is not None:
-            inflight = {u for (u, _r) in self._round_pull}
-            changes, mig = self._control.decide(
-                ev.time, contribs, ev.version,
-                eligible=[u for u in contribs if u not in inflight])
-            if changes:
-                self._apply_cut_changes(changes)
-        for u in contribs:
-            c, s = lora_lib.split_lora(self._global_full, self.cuts[u])
-            self.client_lora[u] = c
-            self.server_lora[u] = lora_lib.embed_in_full_shape(
-                s, self.lora_spec, self.cuts[u], "server")
-            self.heads[u] = self._global_head
-            self.client_opt[u] = self.opt.init(c)
-            self.server_opt[u] = self.opt.init(
-                {"lora": self.server_lora[u], "head": self._global_head})
-            self._client_version[u] += 1   # in-flight rounds of u now race
+        with span("commit.redistribute"):
+            if self._control is not None:
+                inflight = {u for (u, _r) in self._round_pull}
+                changes, mig = self._control.decide(
+                    ev.time, contribs, ev.version,
+                    eligible=[u for u in contribs if u not in inflight])
+                if changes:
+                    self._apply_cut_changes(changes)
+            for u in contribs:
+                c, s = lora_lib.split_lora(self._global_full, self.cuts[u])
+                self.client_lora[u] = c
+                self.server_lora[u] = lora_lib.embed_in_full_shape(
+                    s, self.lora_spec, self.cuts[u], "server")
+                self.heads[u] = self._global_head
+                self._client_version[u] += 1   # in-flight rounds of u now race
+        with span("commit.opt_reset"):
+            for u in contribs:
+                self.client_opt[u] = self.opt.init(self.client_lora[u])
+                self.server_opt[u] = self.opt.init(
+                    {"lora": self.server_lora[u], "head": self._global_head})
         if self.run.agg.transport == "plane":
             # the clock routes the adapter syncs; migrations ride as
             # per-client extras past each contributor's download

@@ -98,10 +98,6 @@ class MetricsRegistry:
                            for k, h in sorted(self._hists.items())},
         }
 
-    def to_json(self) -> str:
-        import json
-        return json.dumps(self.summary(), sort_keys=True)
-
     # ------------------------------------------------------------ persistence
     def state_dict(self) -> dict:
         return {"counters": dict(self._counters),

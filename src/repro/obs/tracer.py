@@ -8,17 +8,17 @@ Perfetto process per kind and one thread per tid, so a 16-client run
 opens in ``chrome://tracing`` as 16 client swimlanes next to the server
 slots and the shared-medium cells.
 
-Storage is columnar (parallel Python lists; ``to_arrays`` gives NumPy
-views) so the vectorized population kernels can bulk-append whole
-rounds with ``add_spans`` — no per-event Python objects on the fast
-path.  ``max_events`` bounds memory as a ring: the OLDEST spans fall
-off first and ``dropped_spans``/``dropped_counters`` record how many.
+Storage is columnar (parallel Python lists) so the vectorized
+population kernels can bulk-append whole rounds with ``add_spans`` — no
+per-event Python objects on the fast path.  ``max_events`` bounds memory
+as a ring: the OLDEST spans fall off first and
+``dropped_spans``/``dropped_counters`` record how many.  Cross-event
+spans (opened when a shared cell admits a transfer, closed when it pops
+it) pair through ``Observability.mark``/``close`` (``repro.obs.des``),
+which serializes its open marks for kill/resume.
 
-Cross-event spans (a shared-medium transfer whose finish instant is
-only known when the cell pops it) pair through ``begin(key, t)`` /
-``end(name, cat, key, t, ...)``; the open-key table serializes with the
-tracer, so a kill/resume at any event boundary replays to the same
-trace as an uninterrupted run (pinned in tests/test_obs_parity.py).
+The host's own spans on the profiler's clock are ``repro.obs.host``'s;
+this tracer stays in simulated seconds.
 """
 from __future__ import annotations
 
@@ -77,8 +77,6 @@ class Tracer:
         self._cval: List[float] = []
         self._ckind: List[str] = []
         self._cid: List[int] = []
-        # open cross-event spans: key -> start time
-        self._open: Dict[str, float] = {}
 
     # ------------------------------------------------------------- recording
     def span(self, name: str, cat: str, t_start: float, t_end: float,
@@ -134,35 +132,6 @@ class Tracer:
             del self._ckind[:k], self._cid[:k]
             self.dropped_counters += k
 
-    def add_counters(self, name: str, ts, values, kind: str, tid: int) -> None:
-        """Bulk counter samples on ONE track (vectorized-kernel path)."""
-        t = np.asarray(ts, dtype=np.float64)
-        v = np.asarray(values, dtype=np.float64)
-        n = len(t)
-        self._cname.extend([name] * n)
-        self._ct.extend(t.tolist())
-        self._cval.extend(v.tolist())
-        self._ckind.extend([kind] * n)
-        self._cid.extend([int(tid)] * n)
-        if self.max_events is not None and len(self._cname) > self.max_events:
-            k = len(self._cname) - self.max_events
-            del self._cname[:k], self._ct[:k], self._cval[:k]
-            del self._ckind[:k], self._cid[:k]
-            self.dropped_counters += k
-
-    def begin(self, key: str, t: float) -> None:
-        """Open a cross-event span (finish instant not yet known)."""
-        self._open[key] = float(t)
-
-    def end(self, name: str, cat: str, key: str, t: float,
-            kind: str, tid: int, attrs: Optional[dict] = None) -> None:
-        """Close a cross-event span opened with :meth:`begin`.  Silently a
-        no-op when ``key`` is not open (the dedicated-link paths emit their
-        spans eagerly and never call ``begin``)."""
-        t0 = self._open.pop(key, None)
-        if t0 is not None:
-            self.span(name, cat, t0, t, kind, tid, attrs)
-
     def _trim_spans(self, k: int) -> None:
         del self._name[:k], self._cat[:k], self._t0[:k], self._t1[:k]
         del self._tkind[:k], self._tid[:k], self._attrs[:k]
@@ -181,18 +150,6 @@ class Tracer:
         return [Span(n, c, a, b, (k, i), at) for n, c, a, b, k, i, at in
                 zip(self._name, self._cat, self._t0, self._t1,
                     self._tkind, self._tid, self._attrs)]
-
-    def to_arrays(self) -> Dict[str, np.ndarray]:
-        """Compact columnar form: names/cats as arrays of str objects,
-        times as float64, tids as int64 (the bench/test-side view)."""
-        return {
-            "name": np.array(self._name, dtype=object),
-            "cat": np.array(self._cat, dtype=object),
-            "t_start": np.array(self._t0, dtype=np.float64),
-            "t_end": np.array(self._t1, dtype=np.float64),
-            "kind": np.array(self._tkind, dtype=object),
-            "tid": np.array(self._tid, dtype=np.int64),
-        }
 
     # ---------------------------------------------------------------- export
     def to_chrome(self, other_data: Optional[dict] = None) -> dict:
@@ -246,8 +203,8 @@ class Tracer:
 
     # ------------------------------------------------------------ persistence
     def state_dict(self) -> dict:
-        """Full JSON-able tracer state (columns + open cross-event spans +
-        drop counters) so kill/resume replays to an identical trace."""
+        """Full JSON-able tracer state (columns + drop counters) so
+        kill/resume replays to an identical trace."""
         return {
             "max_events": self.max_events,
             "dropped": [self.dropped_spans, self.dropped_counters],
@@ -256,10 +213,12 @@ class Tracer:
                       list(self._attrs)],
             "counters": [list(self._cname), list(self._ct), list(self._cval),
                          list(self._ckind), list(self._cid)],
-            "open": dict(self._open),
         }
 
     def load_state_dict(self, st: dict) -> None:
+        """Restore :meth:`state_dict`'s snapshot.  An older snapshot may
+        carry an ``"open"`` table of cross-event spans; those are
+        ``Observability``'s marks now, so it is ignored."""
         self.max_events = st["max_events"]
         self.dropped_spans, self.dropped_counters = (int(x)
                                                      for x in st["dropped"])
@@ -277,4 +236,3 @@ class Tracer:
         self._cval = [float(x) for x in cval]
         self._ckind = [str(x) for x in ckind]
         self._cid = [int(x) for x in cid]
-        self._open = {str(k): float(v) for k, v in st["open"].items()}
