@@ -9,13 +9,20 @@ discounting of the Eq. 6-8 weights (:func:`staleness_weights`, the
 ``(1+s)^-alpha`` family of async FL), and the anchored merge that folds a
 partial contributor buffer into the standing global adapters
 (:func:`merge_into_global`).
+
+The synchronous commit of ``fed/simulator.py`` runs as four jitted programs
+(:func:`commit_aggregate`, :func:`commit_heads`, :func:`commit_redistribute`,
+:func:`commit_opt_reset`), each one device dispatch with the cuts tuple as a
+static argument; the eager functions stay as the reference API.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, List, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import lora as lora_lib
 
@@ -38,15 +45,19 @@ def aggregate_full_weighted(full_loras: Sequence[PyTree],
     with explicit (not necessarily normalized) non-negative weights."""
     if len(full_loras) != len(weights):
         raise ValueError("one weight per adapter tree required")
-    ws = normalize_weights(weights)
+    return _weighted_sum(full_loras, normalize_weights(weights))
 
+
+def _weighted_sum(trees: Sequence[PyTree], ws) -> PyTree:
+    """Leaf-wise ``ws[0]*x0 + ws[1]*x1 + ...`` accumulated in float32, in
+    that order; ``ws`` is a list of floats or a float32 array."""
     def wsum(*leaves):
         acc = ws[0] * leaves[0].astype(jnp.float32)
-        for w, leaf in zip(ws[1:], leaves[1:]):
-            acc = acc + w * leaf.astype(jnp.float32)
+        for i in range(1, len(leaves)):
+            acc = acc + ws[i] * leaves[i].astype(jnp.float32)
         return acc.astype(leaves[0].dtype)
 
-    return jax.tree.map(wsum, *full_loras)
+    return jax.tree.map(wsum, *trees)
 
 
 def aggregate_full(full_loras: Sequence[PyTree], data_sizes: Sequence[int]) -> PyTree:
@@ -218,3 +229,56 @@ def anchored_hierarchical_aggregate(global_full: PyTree,
         cell_masses.append(absent + sum(ws))
     agg = aggregate_full_weighted(summaries, cell_masses)
     return agg, summaries, cell_masses
+
+
+# ------------------------------------------------- the jitted sync commit
+# No buffer is donated: the standing global, the shared heads list and
+# checkpoints may all hold the inputs.
+
+def commit_weights(data_sizes: Sequence[int]) -> np.ndarray:
+    """Eq. 6-8 weights of a commit, normalised in float64 on the host and
+    passed as one float32 array, so new data sizes do not recompile."""
+    return np.asarray(normalize_weights(data_sizes), np.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("cuts",))
+def commit_aggregate(client_loras: Sequence[PyTree],
+                     server_loras: Sequence[PyTree], weights,
+                     cuts: Sequence[int]) -> PyTree:
+    """Alg. 1 l.17-24 as one program: each full-shape server tree split at
+    its client's cut (Eq. 9), joined to the client part (Eq. 5), and the
+    weighted mean of the full trees (Eqs. 6-8), as :func:`aggregation_round`
+    computes it."""
+    fulls = [lora_lib.assemble_full(c, lora_lib.split_lora(s, k)[1], k)
+             for c, s, k in zip(client_loras, server_loras, cuts)]
+    return _weighted_sum(fulls, weights)
+
+
+@jax.jit
+def commit_heads(heads: Sequence[PyTree], weights) -> PyTree:
+    """FedAvg of the classifier heads with the commit's weights."""
+    return _weighted_sum(heads, weights)
+
+
+@functools.partial(jax.jit, static_argnames=("cuts",))
+def commit_redistribute(agg_full: PyTree, cuts: Sequence[int]):
+    """Eq. 9 as one program: the aggregate re-split at each of ``cuts``;
+    the server part placed in full shape, zeros below the cut.  Returns
+    ``(client_loras, server_loras)``, one of each per cut."""
+    clients, servers = [], []
+    for cut in cuts:
+        c, s = lora_lib.split_lora(agg_full, cut)
+        clients.append(c)
+        servers.append(lora_lib.embed_in_full_shape(s, agg_full, cut, "server"))
+    return clients, servers
+
+
+@functools.partial(jax.jit, static_argnames=("opt",))
+def commit_opt_reset(client_loras: Sequence[PyTree], server_lora: PyTree,
+                     head: PyTree, opt):
+    """Fresh optimizer states for the redistributed adapters: ``opt.init``
+    of each client part, and of the server part with the head (every
+    server part has the full shape, so one state serves them all).
+    Returns ``(client_opts, server_opt)``."""
+    return ([opt.init(c) for c in client_loras],
+            opt.init({"lora": server_lora, "head": head}))

@@ -237,37 +237,36 @@ class PopulationTrainer:
         return charge
 
     def _commit_sync_exact(self) -> float:
-        import jax
         n = self.fleet.n
         cuts = [int(c) for c in self._cuts]
-        client_loras, servers_split, heads = [], [], []
+        # an untouched client holds the standing global, full shape
+        client_loras, server_loras, heads = [], [], []
         for u in range(n):
             slot = self.store.peek(u)
             if slot is not None:
                 client_loras.append(slot["client_lora"])
-                servers_split.append(
-                    lora_lib.split_lora(slot["server_lora"], cuts[u])[1])
+                server_loras.append(slot["server_lora"])
                 heads.append(slot["head"])
             else:
-                c, s = self.store.fresh_views(cuts[u])
-                client_loras.append(c)
-                servers_split.append(s)
+                client_loras.append(self.store.fresh_views(cuts[u])[0])
+                server_loras.append(self.store.global_full)
                 heads.append(self.store.global_head)
+        # the Simulator's jitted commit programs, so both engines round alike
+        w = agg_lib.commit_weights(self.data_sizes)
         if self._edges is not None:
-            fulls = [lora_lib.assemble_full(client_loras[u],
-                                            servers_split[u], cuts[u])
+            fulls = [lora_lib.assemble_full(
+                         client_loras[u],
+                         lora_lib.split_lora(server_loras[u], cuts[u])[1],
+                         cuts[u])
                      for u in range(n)]
             agg_full, self.edge_summaries, self.edge_masses = \
                 agg_lib.hierarchical_aggregate(
                     fulls, [float(s) for s in self.data_sizes],
                     [list(cell) for cell in self._edges.cells])
         else:
-            _, _, agg_full = agg_lib.aggregation_round(
-                client_loras, servers_split, cuts, self.data_sizes)
-        w = np.array(self.data_sizes, np.float64)
-        w /= w.sum()
-        head = jax.tree.map(
-            lambda *hs: sum(float(wi) * h for wi, h in zip(w, hs)), *heads)
+            agg_full = agg_lib.commit_aggregate(client_loras, server_loras, w,
+                                                tuple(cuts))
+        head = agg_lib.commit_heads(heads, w)
         up_old = max(self.link.transfer_s(lora_upload_bytes(self.cfg, cut))
                      for cut in cuts)
         self.store.reset_global(agg_full, head)

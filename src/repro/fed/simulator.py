@@ -772,29 +772,26 @@ class Simulator:
             return self._commit_sync_body(ev)
 
     def _commit_sync_body(self, ev) -> Union[float, Dict[int, float]]:
+        # each commit.* span holds one jitted program (core/aggregation.py)
+        w = agg_lib.commit_weights(self.data_sizes)
         with span("commit.aggregate"):
-            servers_split = [lora_lib.split_lora(self.server_lora[u],
-                                                 self.cuts[u])[1]
-                             for u in range(self.u)]
             if self._edges is not None:
                 # two-tier Eq. 6-8: edge cells partially merge their members,
                 # the cloud merges the edge summaries (telescopes to the flat
                 # weighted mean; edge partials kept for inspection/tests)
-                fulls = [lora_lib.assemble_full(self.client_lora[u],
-                                                servers_split[u], self.cuts[u])
+                fulls = [lora_lib.assemble_full(
+                             self.client_lora[u],
+                             lora_lib.split_lora(self.server_lora[u],
+                                                 self.cuts[u])[1],
+                             self.cuts[u])
                          for u in range(self.u)]
                 agg_full, self.edge_summaries, self.edge_masses = \
                     agg_lib.hierarchical_aggregate(
                         fulls, [float(s) for s in self.data_sizes],
                         [list(cell) for cell in self._edges.cells])
-                new_c, new_s = [], []
-                for cut in self.cuts:
-                    c, s = lora_lib.split_lora(agg_full, cut)
-                    new_c.append(c)
-                    new_s.append(s)
             else:
-                new_c, new_s, agg_full = agg_lib.aggregation_round(
-                    self.client_lora, servers_split, self.cuts, self.data_sizes)
+                agg_full = agg_lib.commit_aggregate(
+                    self.client_lora, self.server_lora, w, tuple(self.cuts))
         # the UPLOAD leg shipped the adapters the clients actually trained —
         # price it at the PRE-migration cuts, before any decision applies
         up_old = max(self.link.transfer_s(lora_upload_bytes(self.cfg, cut))
@@ -808,27 +805,24 @@ class Simulator:
                                                     ev.version)
                 if changes:
                     self._apply_cut_changes(changes)
-                    for u in changes:     # re-split the aggregate at the new cut
-                        new_c[u], new_s[u] = lora_lib.split_lora(agg_full,
-                                                                 self.cuts[u])
-            self.client_lora = new_c
-            self.server_lora = [
-                lora_lib.embed_in_full_shape(s, self.lora_spec, cut, "server")
-                for s, cut in zip(new_s, self.cuts)]
+            # re-split the aggregate at the (possibly new) cuts, once per
+            # cut: its clients share the arrays, which no step donates
+            cuts = tuple(sorted(set(self.cuts)))
+            at = [cuts.index(k) for k in self.cuts]
+            clients, servers = agg_lib.commit_redistribute(agg_full, cuts)
+            self.client_lora = [clients[i] for i in at]
+            self.server_lora = [servers[i] for i in at]
         with span("commit.heads"):
             # heads: dataset-weighted FedAvg
-            w = np.array(self.data_sizes, np.float64)
-            w /= w.sum()
-            head = jax.tree.map(
-                lambda *hs: sum(float(wi) * h for wi, h in zip(w, hs)),
-                *self.heads)
+            head = agg_lib.commit_heads(self.heads, w)
             self.heads = [head] * self.u
         self._global_full, self._global_head = agg_full, head
         with span("commit.opt_reset"):
             # optimizer states reset to match redistributed adapters
-            self.client_opt = [self.opt.init(c) for c in self.client_lora]
-            self.server_opt = [self.opt.init({"lora": s, "head": self.heads[u]})
-                               for u, s in enumerate(self.server_lora)]
+            client_opts, server_opt = agg_lib.commit_opt_reset(
+                clients, servers[0], head, self.opt)
+            self.client_opt = [client_opts[i] for i in at]
+            self.server_opt = [server_opt] * self.u
         if self.run.agg.transport == "plane":
             if ev is not None:
                 # the clock ships the adapters through the plane (two-tier
