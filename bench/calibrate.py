@@ -53,11 +53,13 @@ def calibrate(root: Path, workload: str, seeds):
         gc.collect()
         t0 = time.perf_counter()
         with jax.default_matmul_precision("highest"):
-            ref = replay(c, traffic, seed, prog["batches"], sizes, rounds)
+            ref = replay(c, traffic, seed, prog["batches"], sizes, rounds,
+                         root=root)
             ctl = replay(c, traffic, seed, prog["batches"], sizes, rounds,
-                         cdt=jnp.dtype(c["check"]["control"]["compute"]))
+                         cdt=jnp.dtype(c["check"]["control"]["compute"]),
+                         root=root)
             half = replay(c, traffic, seed, prog["batches"], sizes, rounds,
-                          fault="half_batch")
+                          fault="half_batch", root=root)
         line = {"workload": workload, "seed": seed,
                 "replays_s": time.perf_counter() - t0,
                 "program": readings(prog, ref),

@@ -13,10 +13,15 @@ blocks, tanh GELU, no Granite multipliers; the file lists them):
     logits = norm_f(x)[:, 0] · head,   loss = mean cross-entropy
 
 with LoRA adapters (rank r, scale alpha/r) on the projections the file
-names.  Computed in float32 under ``jax.default_matmul_precision("highest")``
-by the caller.  ``cdt`` is the type every matmul operand is rounded to, with
-a per-tensor scale and float32 accumulation: float32 for the reference, a
-narrower type (float8 e4m3) for the control.
+names.  ``bench/reference/replay.py`` loads it for a configuration whose
+``"reference"`` is ``transformer``, through the interface written there.
+It pools the first position, as an encoder's classifier does; under a
+causal mask that position sees one token, so a decoder's classifier needs
+a module of its own.  Computed in float32 under
+``jax.default_matmul_precision("highest")`` by the caller.  ``cdt`` is the
+type every matmul operand is rounded to, with a per-tensor scale and
+float32 accumulation: float32 for the reference, a narrower type (float8
+e4m3) for the control.
 
 Weights are drawn from the seed on the device, in the type the
 configuration serves them in, by the recipe the system under test uses
@@ -218,8 +223,9 @@ def _act(c, x):
     raise ValueError(f"hidden_act {c['hidden_act']!r}")
 
 
-def layer(c: dict, w: dict, lo: dict, x, cdt=F32):
-    """One pre-LN block on x (B, S, d)."""
+def layer(c: dict, w: dict, lo: dict, x, cdt, index):
+    """One pre-LN block on x (B, S, d); every layer is of one kind, so
+    ``index`` is not read."""
     m = dims(c)
     b, s, _ = x.shape
     g = m["H"] // m["KV"]
@@ -256,8 +262,9 @@ def embed(c: dict, w: dict, tokens):
     return x
 
 
-def head_loss(c: dict, w: dict, head, x, labels, cdt=F32):
-    """Mean cross-entropy of the pooled first token's logits."""
+def head_loss(c: dict, w: dict, head, x, labels, tokens, cdt):
+    """Mean cross-entropy of the pooled first token's logits; the first
+    position is the same in every row, so ``tokens`` is not read."""
     h = _norm(c, w["final_norm"], x)[:, 0, :]
     logits = matmul(h, head, cdt)
     logz = jax.nn.logsumexp(logits, axis=-1)

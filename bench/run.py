@@ -19,7 +19,8 @@ a traffic file; nothing here is particular to a cell.  One run:
    records the first periods of the window, for the per-layer metrics of
    ``bench/metrics/<name>.py``;
 5. frees the simulator and replays the first rounds with the plain float32
-   reference (``bench/check.py``): ``correct`` is whether every compared
+   reference that the configuration names (``bench/reference/replay.py``,
+   ``bench/check.py``): ``correct`` is whether every compared
    number is within its limit.  The numbers, each with its limit, end
    standard error and the result line.
 
@@ -203,6 +204,8 @@ def run(argv=None, *, root: Path = ROOT, require_tpu: bool = True,
         return None
     cell = load_cell(root, args.workload)
     c, traffic = cell["config"], cell["traffic"]
+    from bench.reference.replay import reference, replay
+    reference(c, root)          # refuse an unknown reference before the run
     use_compile_cache(root)
 
     import jax
@@ -277,14 +280,13 @@ def run(argv=None, *, root: Path = ROOT, require_tpu: bool = True,
     del sim
     gc.collect()
     from bench.check import readings, verdict
-    from bench.reference.replay import replay
     t_ref = time.perf_counter()
     if program is None:
         values = {k: math.inf for k in c["check"]["limits"]}
     else:
         with jax.default_matmul_precision("highest"):
             ref = replay(c, traffic, args.seed, program["batches"], data_sizes,
-                         check_rounds)
+                         check_rounds, root=root)
         values = readings(program, ref)
     print(f"bench: set-up {setup_s:.1f} s, window {window_s:.1f} s "
           f"({n_rounds} rounds), reference {time.perf_counter() - t_ref:.1f} s",

@@ -9,9 +9,9 @@ how often it ran, its host time, and the device idle inside it; a span
 that the trace lacks is absent, so a reader of a renamed span reads no
 metric rather than zero.
 
-``bench/trace.reduce_trace`` does not call it yet: it would pass the
-Python thread's events, each chip's operations and its window, as
-``tests/bench/test_bench_spans.py`` does.
+``bench/trace.reduce_trace`` calls it with the Python thread's events,
+each chip's operations and the traced window, and hands the result to the
+readers as ``ctx["trace"]["spans"]``.
 """
 from __future__ import annotations
 

@@ -21,6 +21,8 @@ import re
 from collections import defaultdict
 from pathlib import Path
 
+from bench.spans import reduce_spans
+
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 ROUND_SPAN = "bench.round"
 _OP = re.compile(r"^%([\w\-\.]+?)(?:\.\d+)? = ")
@@ -88,7 +90,8 @@ def _host_label(spans, starts, t: float) -> str:
 
 def reduce_trace(trace_dir, top: int = 10) -> dict:
     """Window, device busy time, per-program and per-operation device
-    time, and idle gaps by host activity, from the trace in ``trace_dir``.
+    time, the program's host spans (``bench/spans.py``), and idle gaps by
+    host activity, from the trace in ``trace_dir``.
 
     The window runs from the first ``bench.round`` span's start to the
     later of the last one's end and the last device operation's end.
@@ -115,7 +118,7 @@ def reduce_trace(trace_dir, top: int = 10) -> dict:
                     for e in lines["XLA Modules"].events] if "XLA Modules" in lines else []
             devices.append((ops, mods))
     empty = {"rounds": len(rounds), "window_s": 0.0, "busy_s": 0.0,
-             "programs": {}, "dot_s": 0.0,
+             "programs": {}, "dot_s": 0.0, "spans": {},
              "breakdown": {"device_ops": [], "idle_gaps": []}}
     if not rounds or not devices:
         return empty
@@ -154,5 +157,6 @@ def reduce_trace(trace_dir, top: int = 10) -> dict:
             "busy_s": busy / n * 1e-9,
             "programs": {k: v * 1e-9 for k, v in programs.items()},
             "dot_s": dot / n * 1e-9,
+            "spans": reduce_spans(host, [ops for ops, _ in devices], lo, hi),
             "breakdown": {"device_ops": top_list(op_time),
                           "idle_gaps": top_list(gap_time)}}

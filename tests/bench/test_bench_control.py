@@ -27,16 +27,22 @@ def _tiny(name, kv):
     return c
 
 
-@pytest.mark.parametrize("name,kv", [("bert-base", 4)])
-def test_control_is_not_correct(name, kv):
-    c = _tiny(name, kv)
+def _inputs(c):
+    """paper6's traffic, five rounds of six clients' seeded batches of
+    4 x 32 tokens, and unequal data sizes."""
     t = json.loads((ROOT / "bench/traffic/paper6.json").read_text())
     rounds, n_clients, batch, seq = int(c["check"]["rounds"]), 6, 4, 32
     tokens, labels = emotion_corpus(t["corpus"], rounds * n_clients * batch,
                                     seq, c["vocab_size"], seed=7)
     rows = np.arange(len(labels)).reshape(rounds, n_clients, batch)
     batches = [[(tokens[i], labels[i]) for i in r] for r in rows]
-    sizes = [40, 90, 30, 120, 60, 75]
+    return t, batches, [40, 90, 30, 120, 60, 75], rounds
+
+
+@pytest.mark.parametrize("name,kv", [("bert-base", 4)])
+def test_control_is_not_correct(name, kv):
+    c = _tiny(name, kv)
+    t, batches, sizes, rounds = _inputs(c)
     ctl = c["check"]["control"]
     with jax.default_matmul_precision("highest"):
         ref = replay(c, t, 11, batches, sizes, rounds)
@@ -46,6 +52,55 @@ def test_control_is_not_correct(name, kv):
     assert verdict(readings(ref, ref), limits)[0]
     ok, checks = verdict(readings(low, ref), limits)
     assert not ok, checks
+
+
+# the tiny bert's replay on seed 11, recorded on the CPU before the replay
+# took its reference module from the configuration file
+RECORDED_LOSS = [
+    [2.051889657974243, 2.1683900356292725, 1.7983455657958984,
+     2.2997350692749023, 2.178630828857422, 1.7617061138153076],
+    [2.500826358795166, 2.768766403198242, 1.5941050052642822,
+     1.8551976680755615, 2.2281291484832764, 1.7617425918579102],
+    [3.007902145385742, 2.0869171619415283, 1.9318666458129883,
+     2.1788315773010254, 1.6066839694976807, 1.9194362163543701],
+    [2.0315475463867188, 2.542524576187134, 2.4489083290100098,
+     2.917238712310791, 2.37558650970459, 3.1345603466033936],
+    [1.893447995185852, 1.9637079238891602, 2.401541233062744,
+     2.6645545959472656, 2.0337483882904053, 1.725048303604126]]
+# each leaf's norms summed over clients and layers
+RECORDED_NORMS = {
+    "grad1": {"head": 26.246989965438843, "wk.a": 0.0,
+              "wk.b": 31.369006760418415, "wo.a": 0.0,
+              "wo.b": 111.03587245941162, "wq.a": 0.0,
+              "wq.b": 42.798892229795456, "wv.a": 0.0,
+              "wv.b": 132.68638706207275},
+    "grad_max": {"head": 34.67106103897095, "wk.a": 0.005000197437766474,
+                 "wk.b": 45.217983186244965, "wo.a": 0.022778144862968475,
+                 "wo.b": 137.5441029071808, "wq.a": 0.003930512553779408,
+                 "wq.b": 55.242202028632164, "wv.a": 0.024817120865918696,
+                 "wv.b": 175.0553421974182},
+    "change": {"head": 0.0029514612397179008, "wk.a": 0.004144201171584427,
+               "wk.b": 0.006602457433473319, "wo.a": 0.006301422603428364,
+               "wo.b": 0.008726602303795516, "wq.a": 0.006115915282862261,
+               "wq.b": 0.006899687577970326, "wv.a": 0.007543099753092974,
+               "wv.b": 0.009517676022369415}}
+
+
+def test_replay_reads_as_recorded():
+    """The reference module that the configuration names replays the tiny
+    bert as the hard-wired ``transformer`` did; rtol 1e-6 leaves room only
+    for float32's last bits."""
+    c = _tiny("bert-base", 4)
+    t, batches, sizes, rounds = _inputs(c)
+    with jax.default_matmul_precision("highest"):
+        ref = replay(c, t, 11, batches, sizes, rounds)
+    np.testing.assert_allclose(ref["loss"], RECORDED_LOSS, rtol=1e-6)
+    for key, leaves in RECORDED_NORMS.items():
+        got = {leaf: float(v.sum()) for leaf, v in ref[key].items()}
+        assert sorted(got) == sorted(leaves)
+        np.testing.assert_allclose([got[k] for k in sorted(leaves)],
+                                   [leaves[k] for k in sorted(leaves)],
+                                   rtol=1e-6)
 
 
 def test_float8_rounding_keeps_the_range_and_the_gradient():

@@ -11,7 +11,7 @@ import time
 import pytest
 
 from bench import run as bench_run
-from bench_helpers import (CPU_PEAKS, ROOT, TINY_CELL,  # noqa: F401
+from bench_helpers import (CPU_PEAKS, ROOT, TINY_CELL, added_files,  # noqa: F401
                            no_persistent_cache, tiny_root)
 
 
@@ -103,15 +103,30 @@ def test_command_fails_with_only_the_benchmark_files(tmp_path):
     assert proc.stdout == ""
 
 
+def test_paper6_agg1_is_paper6_committing_every_round():
+    from bench.traffic import load_traffic
+    every = load_traffic(ROOT / "bench/traffic/paper6-agg1.json")
+    fifth = load_traffic(ROOT / "bench/traffic/paper6.json")
+    assert (every["agg_interval"], fifth["agg_interval"]) == (1, 5)
+    differ = {k for k in every.keys() | fifth.keys()
+              if every.get(k) != fifth.get(k)}
+    assert differ == {"name", "agg_interval"}
+
+
+@pytest.mark.parametrize("workload", [
+    w["name"] for w in json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]])
+def test_every_cell_loads_with_its_reference(workload):
+    from bench.reference.replay import reference
+    cell = bench_run.load_cell(ROOT, workload)
+    assert cell["cell"]["name"] == workload
+    assert reference(cell["config"], ROOT).dims(cell["config"])["L"] >= 1
+    assert cell["end_to_end"] and cell["per_layer"]
+
+
 def test_added_cell_is_data_only(tiny_root):
-    """The tiny cell came with two data files and a BENCHMARK.json entry:
-    nothing else of the copy differs from the benchmark."""
-    new = {p.relative_to(tiny_root).as_posix()
-           for p in (tiny_root / "bench").rglob("*")
-           if p.is_file() and "__pycache__" not in p.parts
-           and not (ROOT / p.relative_to(tiny_root)).exists()}
-    assert new == {"bench/configs/bert-tiny.json", "bench/traffic/tiny6.json"}
-    for p in (tiny_root / "bench").rglob("*.py"):
-        if "__pycache__" in p.parts:
-            continue
-        assert p.read_bytes() == (ROOT / p.relative_to(tiny_root)).read_bytes()
+    """The tiny cells came with a configuration, two traffic files and
+    BENCHMARK.json entries: nothing else of the copy differs from the
+    benchmark."""
+    assert added_files(tiny_root) == {"bench/configs/bert-tiny.json",
+                                      "bench/traffic/tiny6.json",
+                                      "bench/traffic/tiny6-agg1.json"}

@@ -157,3 +157,25 @@ def test_the_server_step_is_named_step(reduced):
 def test_every_reader_reads_the_spans_trace(reduced, metric):
     value = load_reader(ROOT, metric)(_ctx(reduced))
     assert value is not None and value >= 0
+
+
+def test_reduce_trace_hands_on_the_spans(trace_dir, reduced):
+    assert reduced["spans"] == _spans(trace_dir)
+
+
+def test_span_readers_read_their_spans(reduced):
+    sp, ctx = reduced["spans"], _ctx(reduced)
+    commit, serve = sp["fed.commit"], sp["fed.serve"]
+
+    def read(metric):
+        return load_reader(ROOT, metric)(ctx)
+
+    assert read("aggregation.idle_ms_per_commit") == pytest.approx(
+        1000 * commit["idle_s"] / commit["count"])
+    assert read("aggregation.dispatches_per_commit") == (
+        commit["dispatches"] / commit["count"])
+    assert read("driver.serve_idle_ms_per_round") == pytest.approx(
+        1000 * serve["idle_s"] / reduced["rounds"])
+    # nearest rank: of 30 serves, the 27th shortest
+    assert read("driver.serve_ms_p90") == pytest.approx(
+        1000 * sorted(serve["durations"])[26])

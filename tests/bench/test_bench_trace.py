@@ -54,11 +54,25 @@ def _ctx(reduced):
             "peaks": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}}
 
 
-@pytest.mark.parametrize("metric", [
-    m["name"] for m in json.loads((ROOT / "BENCHMARK.json").read_text())["per_layer"]])
+# the readers this trace was recorded for; it holds no program spans
+TRACE_READERS = ["device.idle_share", "step_mfu", "driver.compiles_in_window",
+                 "driver.round_max_ms", "server_step.device_ms_per_round",
+                 "client_prefix.device_ms_per_round", "kernels.matmul_roofline"]
+SPAN_READERS = ["driver.serve_idle_ms_per_round", "driver.serve_ms_p90",
+                "aggregation.idle_ms_per_commit",
+                "aggregation.dispatches_per_commit"]
+
+
+@pytest.mark.parametrize("metric", TRACE_READERS)
 def test_every_reader_reads_the_trace(reduced, metric):
     value = load_reader(ROOT, metric)(_ctx(reduced))
     assert value is not None and value >= 0
+
+
+@pytest.mark.parametrize("metric", SPAN_READERS)
+def test_span_readers_are_silent_without_their_spans(reduced, metric):
+    assert reduced["spans"] == {}
+    assert load_reader(ROOT, metric)(_ctx(reduced)) is None
 
 
 def test_device_readers_are_silent_without_a_trace():
@@ -67,7 +81,8 @@ def test_device_readers_are_silent_without_a_trace():
            "peaks": {"bf16_flops_per_s": 1.0, "hbm_bytes_per_s": 1.0}}
     for metric in ("device.idle_share", "step_mfu", "kernels.matmul_roofline",
                    "server_step.device_ms_per_round",
-                   "client_prefix.device_ms_per_round", "driver.round_max_ms"):
+                   "client_prefix.device_ms_per_round", "driver.round_max_ms",
+                   *SPAN_READERS):
         assert load_reader(ROOT, metric)(ctx) is None
 
 
