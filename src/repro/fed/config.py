@@ -477,11 +477,12 @@ def validate_run_config(run: FedRunConfig,
             raise ValueError("mid-flight snapshots, resume and preemption "
                              "are event-clock notions (the closed form has "
                              "no in-flight state); set engine mode='event'")
-        if run.obs.enabled:
-            raise ValueError("observability (obs trace/metrics/"
-                             "memory_ledger) instruments the event clock's "
-                             "spans; the closed form has no events — set "
-                             "engine mode='event'")
+        if run.obs.trace or run.obs.memory_ledger:
+            raise ValueError("the obs span tracer and memory ledger "
+                             "instrument the event clock's spans; the closed "
+                             "form has no events — set engine mode='event' "
+                             "(obs metrics alone count the round's loss "
+                             "reads)")
     else:   # event
         if run.scheme != "ours":
             # the DES models the paper's single shared-server queue; sfl

@@ -436,6 +436,10 @@ class Simulator:
             if run.scheme in ("ours", "sfl") and (rnd + 1) % run.agg.interval == 0:
                 self.sim_clock += self._commit_sync(None)
 
+            if run.scheme != "sl":
+                # the round's one wait on the device, after the commit's
+                # dispatch: the serves only started each loss's copy
+                losses = self._read_losses(losses)
             # a deadline can cut every client out of a round -> no losses
             mean_loss = float(np.mean(losses)) if losses else float("nan")
         rec = RoundRecord(rnd, self.sim_clock, mean_loss)
@@ -456,12 +460,25 @@ class Simulator:
             losses.extend(self._serve_group(list(grp)))
         return losses, order
 
-    def _serve_group(self, grp: List[int]) -> List[float]:
+    def _read_losses(self, losses: List[jax.Array]) -> List[float]:
+        """The losses as Python floats.  With obs metrics on, counts them
+        (``loss_reads``) and those whose step was still running when read
+        (``loss_reads_waited``): there the host had got ahead of the
+        device."""
+        if self.obs is not None and self.obs.metrics is not None:
+            self.obs.metrics.inc("loss_reads", len(losses))
+            self.obs.metrics.inc("loss_reads_waited",
+                                 sum(not loss.is_ready() for loss in losses))
+        return [float(loss) for loss in losses]
+
+    def _serve_group(self, grp: List[int]) -> List[jax.Array]:
         """Run the real jitted math for one server dispatch group: per-client
         batch draw + client forward (with optional int8+EF uplink), then the
         sequential server step (size-1 group) or ONE batched vmapped dispatch
         (size>1), then each client's backward.  Shared by the analytic round
-        body and the FederationClock's serve events.
+        body and the FederationClock's serve events.  Returns each client's
+        loss as a device scalar whose copy to the host has started: reading
+        it here would drain the device's queue before the next dispatch.
 
         The host span ``fed.serve`` covers the group, from the batch draw to
         the last backward dispatch (``repro.obs.host``)."""
@@ -470,7 +487,7 @@ class Simulator:
         with span("fed.serve", round=self._serve_round, **ids):
             return self._serve_group_body(grp)
 
-    def _serve_group_body(self, grp: List[int]) -> List[float]:
+    def _serve_group_body(self, grp: List[int]) -> List[jax.Array]:
         run = self.run
         batches, acts = {}, {}
         for u in grp:
@@ -488,7 +505,7 @@ class Simulator:
                     v = dequantize(qx, v.dtype)
                 acts[u] = v
 
-        losses: List[float] = []
+        losses: List[jax.Array] = []
         if len(grp) == 1:
             u = grp[0]
             cut = self.cuts[u]
@@ -497,7 +514,8 @@ class Simulator:
                     self.params, self.server_lora[u], self.heads[u],
                     self.server_opt[u], acts[u], batches[u])
             with span("fed.loss_read"):
-                losses.append(float(loss))
+                loss.copy_to_host_async()
+                losses.append(loss)
             with span("fed.client_bwd", uid=u):
                 self._apply_server_update(u, new_lora, new_head, new_opt)
                 self._client_backward(u, batches[u], dv)
@@ -516,7 +534,9 @@ class Simulator:
             nls, nos = lora_lib.unstack_tree(nl), lora_lib.unstack_tree(no)
         for i, u in enumerate(grp):
             with span("fed.loss_read"):
-                losses.append(float(loss_g[i]))
+                loss = loss_g[i]
+                loss.copy_to_host_async()
+                losses.append(loss)
             with span("fed.client_bwd", uid=u):
                 self._apply_server_update(u, nls[i], nh[i], nos[i])
                 self._client_backward(u, batches[u], dv_g[i])
@@ -710,7 +730,7 @@ class Simulator:
         rounds = sorted(set(ev.rounds))
         self._serve_round = (rounds[0] if len(rounds) == 1
                              else " ".join(map(str, rounds)))
-        losses = self._serve_group(list(ev.uids))
+        losses = [float(loss) for loss in self._serve_group(list(ev.uids))]
         for u, (r, pull_version, cur_lora, cur_opt) in swapped.items():
             if self._client_version[u] != pull_version:
                 # a commit refreshed u while this round was in flight: the

@@ -28,7 +28,8 @@ HOST_SPANS = (
     "fed.serve",            # one dispatch group of _serve_group
     "fed.client_fwd",       # batch draw + upload + client forward (+ int8 uplink)
     "fed.server_step",      # dispatch of the server step
-    "fed.loss_read",        # the blocking float(loss)
+    "fed.loss_read",        # starts the copy of the client's loss to the host;
+                            # the round reads all its losses once, after its commit
     "fed.client_bwd",       # server update applied + client backward
     "fed.commit",           # _commit_sync / _commit_async
     "commit.aggregate",     # split + aggregation of the adapters

@@ -119,6 +119,11 @@ def test_analytic_plane_transport_is_now_valid():
     validate_run_config(FedRunConfig(agg=AggConfig(transport="plane")), 6)
 
 
+def test_analytic_obs_metrics_is_valid():
+    """The analytic round counts its loss reads in the obs metrics."""
+    validate_run_config(FedRunConfig(obs=ObsConfig(metrics=True)), 6)
+
+
 BAD = [
     (KeyError, dict(fleet=FleetConfig(sampling="bogus"))),
     (ValueError, dict(fleet=FleetConfig(sampling="full", rate=0.5))),
@@ -149,8 +154,9 @@ BAD = [
                       obs=ObsConfig(max_events=100))),
     (ValueError, dict(engine=EngineConfig(mode="event"),
                       obs=ObsConfig(trace=True, max_events=0))),
-    # the closed-form engine has no event stream to observe
-    (ValueError, dict(obs=ObsConfig(metrics=True))),
+    # the closed-form engine has no event stream to trace or to ledger
+    (ValueError, dict(obs=ObsConfig(trace=True))),
+    (ValueError, dict(obs=ObsConfig(memory_ledger=True))),
 ]
 
 
