@@ -11,7 +11,7 @@ Two execution paths, identical semantics (tested against each other):
   * path="sliced": static cut, python loop over owned layers only — what the
     federated simulator runs on CPU;
   * path="scan":   masked lax.scan with a *traced* cut — the production
-    form: one compiled executable serves every client (DESIGN.md §4).
+    form: one compiled executable serves every client (docs/architecture.md).
 """
 from __future__ import annotations
 
@@ -421,36 +421,34 @@ def make_full_train_step(model, opt: AdamW, *, remat: bool = False,
 
 
 class CohortAdapterStore:
-    """Cohort-indexed per-client adapter + optimizer state for population-
-    scale federation: only the SAMPLED clients ever hold materialized
-    trees.
+    """Per-client adapter, head and optimizer state, one slot per client:
+    ``(client_lora, client_opt, server_lora, head, server_opt)``.
 
-    The per-object ``Simulator`` eagerly builds every client's
-    ``(client_lora, client_opt, server_lora, head, server_opt)`` tuple at
-    init and re-builds ALL of them from the aggregated global at each sync
-    commit.  At 10^4 clients that is the memory wall this store removes:
-    it keeps ONE standing global ``(full adapter, head)`` plus a dict of
-    slots for the clients a cohort actually touched, and materializes a
-    slot on first use from a per-cut TEMPLATE cache —
+    It keeps ONE standing global ``(full adapter, head)`` plus a dict of
+    slots, and builds a slot from a per-cut TEMPLATE, shared by every
+    client at that cut (slots are shallow copies, so their trees alias
+    until a training step replaces them).  Who materializes when is the
+    caller's choice: ``fed.split_math.SplitMath`` materializes every client
+    of a small fleet at once, and at population scale only the clients a
+    cohort touches, so 10^4 clients cost a cohort's memory.
+
+    A template comes from the sync commit's jitted programs
+    (``reset_global(..., templates)``), or else is built on first use from
+    the standing global —
 
         client_lora = split_lora(global_full, cut)[0]
         server_lora = embed_in_full_shape(split[1], spec, cut, "server")
         opt states  = opt.init(...) on those trees
 
-    ``split_lora``/``embed_in_full_shape`` are pure slice/scatter ops and
-    ``opt.init`` is deterministic, so a materialized slot is bit-identical
-    to the eager Simulator's standing state for an untouched client — the
-    cross-engine parity grid in tests/test_population_training.py leans on
-    exactly this equivalence.  Distinct cuts share one template; slots are
-    shallow copies, so untouched trees alias until a training step
-    replaces them.
+    — the same pure slice/scatter ops and deterministic ``opt.init``, so
+    both give the same bits.
 
-    Two global-update modes mirror the two commit families:
+    Two global-update modes follow the two commit families:
       * ``reset_global``  (sync barrier): every client re-enters from the
-        new global -> drop ALL slots and caches;
+        new global -> drop ALL slots;
       * ``set_global``    (async): non-contributors keep training on their
-        in-flight state -> keep slots, invalidate only the fresh-view
-        caches; callers re-materialize the contributors via ``drop``.
+        in-flight state -> keep slots, drop only the templates; callers
+        re-materialize the contributors via ``drop``.
     """
 
     def __init__(self, lora_spec, opt: AdamW, global_full, global_head,
@@ -462,7 +460,6 @@ class CohortAdapterStore:
         self._cut_of = cut_of            # uid -> cut
         self._slots: dict = {}           # uid -> slot dict
         self._templates: dict = {}       # cut -> template slot
-        self._views: dict = {}           # cut -> (client_view, server_split)
         self._slot_nbytes: dict = {}     # cut -> bytes one slot holds
 
     # ----------------------------------------------------------- materialize
@@ -497,25 +494,16 @@ class CohortAdapterStore:
     def slot(self, u: int) -> dict:
         return self._slots[int(u)]
 
-    def peek(self, u: int):
-        """The slot if materialized, else None (no side effects)."""
-        return self._slots.get(int(u))
+    def state_of(self, u: int) -> dict:
+        """Client ``u``'s state: its slot, or for an untouched client its
+        cut's template (read only; no slot is made)."""
+        slot = self._slots.get(int(u))
+        return slot if slot is not None \
+            else self._template(int(self._cut_of(u)))
 
     def touched(self):
         """Materialized uids, ascending."""
         return sorted(self._slots)
-
-    def fresh_views(self, cut: int):
-        """``(client_view, server_split_view)`` of the standing global at
-        ``cut`` — what an untouched client's state looks like, shared
-        across every absent client at that cut (cached slices, no
-        per-client copies)."""
-        pr = self._views.get(cut)
-        if pr is None:
-            from repro.core import lora as lora_lib
-            pr = lora_lib.split_lora(self.global_full, cut)
-            self._views[cut] = pr
-        return pr
 
     # ---------------------------------------------------------- global swaps
     def drop(self, u: int) -> None:
@@ -526,11 +514,12 @@ class CohortAdapterStore:
         self.global_full = full
         self.global_head = head
         self._templates.clear()
-        self._views.clear()
 
-    def reset_global(self, full, head) -> None:
-        """Sync barrier commit: new global, every slot re-enters fresh."""
+    def reset_global(self, full, head, templates=None) -> None:
+        """Sync barrier commit: new global, every slot re-enters fresh,
+        from ``templates`` (cut -> slot) where the commit built them."""
         self.set_global(full, head)
+        self._templates.update(templates or {})
         self._slots.clear()
 
     # ------------------------------------------------------------ accounting

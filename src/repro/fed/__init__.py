@@ -11,8 +11,9 @@ from repro.fed.fleet import FleetSpec
 from repro.fed.population import (PopulationClock, PopulationFleet,
                                   PopulationResult, sample_cohort,
                                   step_time_arrays, vectorized_round)
-from repro.fed.simulator import (LINK_MODELS, FedRunConfig, RoundRecord,
-                                 Simulator, validate_run_config)
+from repro.fed.simulator import (LINK_MODELS, FedRunConfig, Simulator,
+                                 validate_run_config)
+from repro.fed.split_math import RoundRecord
 
 __all__ = ["AGG_POLICIES", "AggConfig", "ClockConfig", "ClockResult",
            "CommitEvent", "ControlConfig", "EngineConfig", "EngineResult",

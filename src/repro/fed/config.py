@@ -143,6 +143,16 @@ class AggConfig:
                              "knob would be silently ignored otherwise)")
 
 
+def resolve_buffer_k(agg: AggConfig, n_clients: int) -> int:
+    """The async commit threshold: ``agg.buffer_k``, or by default a
+    semi-sync half-cohort under ``buffered`` and every upload under
+    ``staleness`` (fully async; the discount keeps stale ones from
+    dominating).  The one rule both real-math drivers commit by."""
+    if agg.buffer_k is not None:
+        return agg.buffer_k
+    return 1 if agg.policy == "staleness" else max(1, n_clients // 2)
+
+
 @dataclasses.dataclass(frozen=True, eq=True)
 class NetConfig:
     """Network-plane knobs (repro/net)."""

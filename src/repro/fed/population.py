@@ -65,7 +65,7 @@ from repro.core.cost_model import (BWD_FACTOR, DeviceProfile, StepTimes,
                                    lora_flops_per_token_per_layer,
                                    lora_upload_bytes)
 from repro.core.scheduling import alg2_priorities, resolve_online
-from repro.fed.config import FedRunConfig
+from repro.fed.config import FedRunConfig, resolve_buffer_k
 from repro.fed.engine import (DISCIPLINES, ClockConfig, EngineResult,
                               FederationClock, Job, ServiceRecord,
                               simulate_round)
@@ -758,7 +758,7 @@ class PopulationClock:
                 if (rnd + 1) % run.agg.interval == 0:
                     # the per-object engine commits at every interval
                     # boundary, empty served set included; the charge is
-                    # the trainer's Simulator-mirrored nominal round trip
+                    # the trainer's nominal round trip, the Simulator's charge
                     t0c = self.now
                     charge = tr.commit_sync()
                     self.now = max(self.now, self.now + charge)
@@ -906,16 +906,12 @@ class PopulationClock:
         """The one async clock configuration BOTH kernels run — parity by
         construction."""
         run = self.run_cfg
-        if run.agg.buffer_k is not None:
-            buffer_k = run.agg.buffer_k
-        elif self._trainer is not None:
-            # real-math runs resolve the Simulator's default (semi-sync
-            # half-cohort for buffered, fully async under staleness) so
-            # the parity oracle and the trainer commit at the same events
-            buffer_k = (1 if run.agg.policy == "staleness"
-                        else max(1, self.fleet.n // 2))
-        else:
+        if run.agg.buffer_k is None and self._trainer is None:
             buffer_k = self.fleet.n
+        else:
+            # real-math runs commit by the rule both drivers share, so
+            # they commit at the same events
+            buffer_k = resolve_buffer_k(run.agg, self.fleet.n)
         return ClockConfig(policy=self._policy, slots=run.engine.slots,
                            cohort_chunk=run.engine.cohort_chunk,
                            chunk_efficiency=run.engine.chunk_efficiency,

@@ -11,7 +11,9 @@
 
 Model math runs for real in JAX (client forward, server resume-at-cut,
 activation-gradient backprop, LoRA/Adam updates, FedAvg aggregation);
-wall-clock and memory come from the §IV/§V analytical models (DESIGN.md §10).
+wall-clock and memory come from the §IV/§V analytical models
+(docs/architecture.md).  The math itself, and the per-client state it
+reads and writes, live in ``fed/split_math.py``.
 """
 from __future__ import annotations
 
@@ -25,9 +27,8 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.control import CONTROLLERS, ControlLoop
-from repro.core import aggregation as agg_lib
 from repro.core import lora as lora_lib
-from repro.core import memory_model, splitfl
+from repro.core import memory_model
 from repro.core.cost_model import (DeviceProfile, LinkProfile, StepTimes,
                                    client_step_times, dtype_nbytes,
                                    lora_upload_bytes, makespan)
@@ -37,20 +38,19 @@ from repro.core.scheduling import (ONLINE_DISCIPLINES, SCHEDULERS,
                                    alg2_priorities, refresh_priorities,
                                    resolve_online, resolve_order)
 from repro.data import ClassificationLoader, EmotionDataset, dirichlet_partition
-from repro.fed import metrics as M
 # the run configuration moved to fed/config.py (grouped sub-configs with
 # flat-kwarg compatibility shims); re-exported here so every existing
 # ``from repro.fed.simulator import FedRunConfig`` keeps working
 from repro.fed.config import (AggConfig, ControlConfig, EngineConfig,  # noqa: F401
                               FedRunConfig, FleetConfig, LINK_MODELS,
-                              NetConfig, ObsConfig, validate_run_config)
+                              NetConfig, ObsConfig, resolve_buffer_k,
+                              validate_run_config)
 from repro.fed.devices import LINK, SERVER
 from repro.fed.engine import (AGG_POLICIES, ClockConfig, FederationClock,
                               RoundPlan, jobs_from_times)
+from repro.fed.split_math import RoundRecord, SplitMath, round_id
 from repro.net.topology import EdgeTopology
-from repro.models import build_model
 from repro.obs.host import span
-from repro.optim import AdamW
 
 SFL_FRAGMENTATION = 1.04   # multi-model GPU contention overhead (paper §V-B)
 
@@ -59,15 +59,6 @@ SFL_FRAGMENTATION = 1.04   # multi-model GPU contention overhead (paper §V-B)
 # at the 100 Mbps / ~0.5 s-transfer scale of the paper's setup
 GE_BAD_FRACTION = 0.1
 GE_P_GB, GE_P_BG, GE_DWELL_S = 0.2, 0.4, 0.5
-
-
-@dataclasses.dataclass
-class RoundRecord:
-    round: int
-    sim_time_s: float
-    mean_loss: float
-    accuracy: Optional[float] = None
-    f1: Optional[float] = None
 
 
 class Simulator:
@@ -95,11 +86,7 @@ class Simulator:
         if run.fleet.size is not None and run.fleet.size != len(devices):
             raise ValueError(f"run.fleet.size={run.fleet.size} but "
                              f"{len(devices)} devices were materialized")
-        if run.engine.fused_lora:
-            # thread the kernel choice through config — no process-global
-            # state (the deprecated set_fused_lora shim is gone from here)
-            cfg = cfg.with_(lora=dataclasses.replace(cfg.lora, impl="fused"))
-        self.cfg, self.run = cfg, run
+        self.run = run
         self.devices, self.cuts = list(devices), [int(c) for c in cuts]
         self._init_cuts = [int(c) for c in cuts]   # fingerprint anchor
         self.link, self.server_dev = link, server
@@ -130,10 +117,14 @@ class Simulator:
                     backhaul_mbps=run.fleet.backhaul_mbps,
                     cell_capacity_mbps=run.fleet.edge_capacity_mbps)
         self._cap_ranks: Optional[np.ndarray] = None
-        self.model = build_model(cfg)
-        rng = jax.random.PRNGKey(run.seed)
-        self.params = self.model.init_params(rng)
-        self.lora_spec = jax.eval_shape(self.model.init_lora, rng)
+        # the model, every client's adapter/head/optimizer state (all
+        # materialized) and the jitted math; it reads the live cuts list,
+        # which is only ever changed in place
+        self.math = SplitMath(cfg, run, self.cuts.__getitem__, self.u,
+                              test=test, dense=True)
+        self.cfg = cfg = self.math.cfg
+        self.store, self.params, self.opt = (self.math.store,
+                                             self.math.params, self.math.opt)
 
         # non-IID data
         parts = dirichlet_partition(train.labels, self.u, run.alpha, run.seed)
@@ -141,47 +132,9 @@ class Simulator:
         self.loaders = [ClassificationLoader(train.subset(p), run.batch_size,
                                              seed=run.seed + i)
                         for i, p in enumerate(parts)]
-        self.test = test
-
-        # per-client state
-        base_lora = self.model.init_lora(jax.random.PRNGKey(run.seed + 1))
-        self.opt = AdamW(run.lr)
-        self.client_params = []
-        self.client_lora: List = []
-        self.server_lora: List = []
-        self.heads: List = []
-        self.client_opt: List = []
-        self.server_opt: List = []
-        head0 = self.params.get("cls_head")
-        for i, cut in enumerate(self.cuts):
-            pc = dict(self.params)
-            pc["layers"] = lora_lib.slice_stack(self.params["layers"], 0, cut)
-            self.client_params.append(pc)
-            c, s = lora_lib.split_lora(base_lora, cut)
-            full_shape = lora_lib.embed_in_full_shape(s, self.lora_spec, cut, "server")
-            self.client_lora.append(c)
-            self.server_lora.append(full_shape)
-            self.heads.append(head0)
-            self.client_opt.append(self.opt.init(c))
-            self.server_opt.append(self.opt.init({"lora": full_shape, "head": head0}))
-
-        # jitted steps per distinct cut
-        self._srv_steps = {}
-        self._cli_steps = {}
+        # the frozen client prefix and jitted steps, once per distinct cut
         for cut in sorted(set(self.cuts)):
-            self._srv_steps[cut] = splitfl.make_server_step_cls(
-                self.model, self.opt, path="sliced", static_cut=cut)
-            self._cli_steps[cut] = splitfl.make_client_step(
-                self.model, self.opt, cut, path="sliced")
-        # cohort-batched server step: ONE vmapped executable with traced
-        # per-client cuts serves any chunk handed over by the round clock
-        # (cohort_impl="ragged" instead groups the chunk by cut value and
-        # runs each group's [cut, L) suffix over a concatenated batch)
-        self._srv_step_batched = splitfl.make_server_step_cls_batched(
-            self.model, self.opt, impl=run.engine.cohort_impl)
-        # held-out logits; built once so every evaluate() reuses one trace
-        self._eval_logits = jax.jit(
-            lambda p, lo, b: self.model.loss(p, lo, b, path="scan")[1])
+            self.math.at_cut(cut)
         self._last_event = None   # EngineResult of the last event-driven round
 
         # analytic per-step Eq.10 terms (fixed per client); wireless terms
@@ -222,30 +175,20 @@ class Simulator:
                         if run.obs.memory_ledger else None))
             if self._control is not None:
                 self._control.obs = self.obs
+            self.math.obs = self.obs
         self.history: List[RoundRecord] = []
         self.sim_clock = 0.0
         # beyond-paper transport/participation state
         self._round_rng = np.random.default_rng(run.seed + 7777)
-        self._ef_residual = [None] * self.u      # uplink error feedback
         self._active: List[int] = list(range(self.u))
-        # continuous-time engine state: the standing global model (updated at
-        # every aggregation commit; the async policies merge INTO it), the
-        # per-serve loss trace for wall-clock curves, and the per-client-round
-        # straggler rng (the sync path re-rolls per barrier wave instead)
-        self._global_full = base_lora
-        self._global_head = head0
+        # continuous-time engine state: the per-serve loss trace for
+        # wall-clock curves and the per-client-round straggler rng (the
+        # sync path re-rolls per barrier wave instead)
         self.loss_events: List[tuple] = []   # (t_server_done, uid, round, loss)
         self._clock: Optional[FederationClock] = None
         self._wave_losses: List[float] = []
         self._async_rng = np.random.default_rng(run.seed + 4242)
         self._quant_ratio: Optional[float] = None
-        # causal consistency for in-flight async rounds: the client-side
-        # state each (uid, round) pulled at round start, a per-client commit
-        # counter, and the local updates discarded because a commit
-        # refreshed the client while its round was still in flight
-        self._round_pull: dict = {}
-        self._client_version = [0] * self.u
-        self.discarded_updates: List[tuple] = []   # (uid, round)
         # the round (or rounds) being served: the host spans' ``round`` id
         self._serve_round: Union[int, str, None] = None
         # mid-flight checkpoint/resume plumbing (docs/checkpointing.md):
@@ -472,89 +415,26 @@ class Simulator:
         return [float(loss) for loss in losses]
 
     def _serve_group(self, grp: List[int]) -> List[jax.Array]:
-        """Run the real jitted math for one server dispatch group: per-client
-        batch draw + client forward (with optional int8+EF uplink), then the
-        sequential server step (size-1 group) or ONE batched vmapped dispatch
-        (size>1), then each client's backward.  Shared by the analytic round
-        body and the FederationClock's serve events.  Returns each client's
-        loss as a device scalar whose copy to the host has started: reading
-        it here would drain the device's queue before the next dispatch.
+        """One server dispatch group's real math (``SplitMath.serve``),
+        shared by the analytic round body and the FederationClock's serve
+        events: each client's loss as a device scalar whose copy to the
+        host has started."""
+        return self.math.serve(grp, self.loaders.__getitem__,
+                               self._serve_round)
 
-        The host span ``fed.serve`` covers the group, from the batch draw to
-        the last backward dispatch (``repro.obs.host``)."""
-        ids = ({"uid": grp[0]} if len(grp) == 1
-               else {"uids": " ".join(map(str, grp))})
-        with span("fed.serve", round=self._serve_round, **ids):
-            return self._serve_group_body(grp)
+    # read-only views of the store: each client's trees as a list (the
+    # checkpoint format, and callers that read one client's state)
+    client_lora = property(lambda self: self._field("client_lora"))
+    server_lora = property(lambda self: self._field("server_lora"))
+    heads = property(lambda self: self._field("head"))
+    client_opt = property(lambda self: self._field("client_opt"))
+    server_opt = property(lambda self: self._field("server_opt"))
+    _global_full = property(lambda self: self.store.global_full)
+    _global_head = property(lambda self: self.store.global_head)
+    discarded_updates = property(lambda self: self.math.discarded_updates)
 
-    def _serve_group_body(self, grp: List[int]) -> List[jax.Array]:
-        run = self.run
-        batches, acts = {}, {}
-        for u in grp:
-            with span("fed.client_fwd", uid=u):
-                batch = {k: jnp.asarray(v)
-                         for k, v in self.loaders[u].next_batch().items()}
-                batches[u] = batch
-                fwd, _ = self._cli_steps[self.cuts[u]]
-                v = fwd(self.client_params[u], self.client_lora[u], batch)
-                if run.net.quantize:
-                    # int8 + error-feedback uplink (repro/comm)
-                    from repro.comm import dequantize, quantize_with_feedback
-                    qx, self._ef_residual[u] = quantize_with_feedback(
-                        v, self._ef_residual[u])
-                    v = dequantize(qx, v.dtype)
-                acts[u] = v
-
-        losses: List[jax.Array] = []
-        if len(grp) == 1:
-            u = grp[0]
-            cut = self.cuts[u]
-            with span("fed.server_step", cut=cut):
-                loss, new_lora, new_head, new_opt, dv = self._srv_steps[cut](
-                    self.params, self.server_lora[u], self.heads[u],
-                    self.server_opt[u], acts[u], batches[u])
-            with span("fed.loss_read"):
-                loss.copy_to_host_async()
-                losses.append(loss)
-            with span("fed.client_bwd", uid=u):
-                self._apply_server_update(u, new_lora, new_head, new_opt)
-                self._client_backward(u, batches[u], dv)
-            return losses
-        # batched cohort chunk: one vmapped dispatch for the whole group
-        with span("fed.server_step",
-                  cut=" ".join(str(self.cuts[u]) for u in grp)):
-            loss_g, nl, nh, no, dv_g = self._srv_step_batched(
-                self.params,
-                lora_lib.stack_trees([self.server_lora[u] for u in grp]),
-                jnp.stack([self.heads[u] for u in grp]),
-                lora_lib.stack_trees([self.server_opt[u] for u in grp]),
-                jnp.stack([acts[u] for u in grp]),
-                lora_lib.stack_trees([batches[u] for u in grp]),
-                jnp.asarray([self.cuts[u] for u in grp]))
-            nls, nos = lora_lib.unstack_tree(nl), lora_lib.unstack_tree(no)
-        for i, u in enumerate(grp):
-            with span("fed.loss_read"):
-                loss = loss_g[i]
-                loss.copy_to_host_async()
-                losses.append(loss)
-            with span("fed.client_bwd", uid=u):
-                self._apply_server_update(u, nls[i], nh[i], nos[i])
-                self._client_backward(u, batches[u], dv_g[i])
-        return losses
-
-    def _apply_server_update(self, u: int, new_lora, new_head, new_opt):
-        self.server_lora[u] = new_lora
-        self.heads[u] = new_head
-        self.server_opt[u] = new_opt
-
-    def _client_backward(self, u: int, batch, dv):
-        if self.run.net.quantize:
-            from repro.comm import dequantize, quantize
-            dv = dequantize(quantize(dv), dv.dtype)   # downlink int8
-        _, bwd = self._cli_steps[self.cuts[u]]
-        self.client_lora[u], self.client_opt[u] = bwd(
-            self.client_params[u], self.client_lora[u],
-            self.client_opt[u], batch, dv)
+    def _field(self, key: str) -> list:
+        return [self.store.slot(u)[key] for u in range(self.u)]
 
     def _round_sl(self):
         """SL baseline: ONE traveling full adapter set (kept in slot 0 as a
@@ -562,28 +442,28 @@ class Simulator:
         the traveling adapters at its own cut, trains, and folds back."""
         order = list(range(self.u))
         losses = []
+        travel = self.store.slot(0)
         for u in order:
             cut = self.cuts[u]
             batch = {k: jnp.asarray(v) for k, v in self.loaders[u].next_batch().items()}
             # hand-off: client receives the traveling client-side adapters
-            cli_lo, _ = lora_lib.split_lora(self.server_lora[0], cut)
-            fwd, bwd = self._cli_steps[cut]
-            v = fwd(self.client_params[u], cli_lo, batch)
-            loss, new_lora, new_head, new_opt, dv = self._srv_steps[cut](
-                self.params, self.server_lora[0], self.heads[0],
-                self.server_opt[0], v, batch)
-            self.server_lora[0] = new_lora
-            self.heads[0] = new_head
-            self.server_opt[0] = new_opt
+            cli_lo, _ = lora_lib.split_lora(travel["server_lora"], cut)
+            steps = self.math.at_cut(cut)
+            v = steps.client_fwd(steps.prefix, cli_lo, batch)
+            loss, new_lora, new_head, new_opt, dv = steps.server_step(
+                self.params, travel["server_lora"], travel["head"],
+                travel["server_opt"], v, batch)
+            travel["server_lora"], travel["head"], travel["server_opt"] = \
+                new_lora, new_head, new_opt
             losses.append(float(loss))
-            new_cli, _ = bwd(self.client_params[u], cli_lo,
-                             self.opt.init(cli_lo), batch, dv)
-            self._sl_fold_back(new_cli, cut)
+            new_cli, _ = steps.client_bwd(steps.prefix, cli_lo,
+                                          self.opt.init(cli_lo), batch, dv)
+            self._sl_fold_back(travel, new_cli, cut)
         return losses, order
 
-    def _sl_fold_back(self, client_part, cut: int):
+    def _sl_fold_back(self, travel: dict, client_part, cut: int):
         """Write the client's updated prefix back into the traveling set."""
-        full = self.server_lora[0]
+        full = travel["server_lora"]
         merged = {}
         for key, sub in full.items():
             if key in lora_lib.STACKED_KEYS and key in client_part:
@@ -592,7 +472,7 @@ class Simulator:
                     sub, client_part[key])
             else:
                 merged[key] = sub
-        self.server_lora[0] = merged
+        travel["server_lora"] = merged
 
     # ---------------------------------------------------- event-engine driver
     # Under engine="event" the FederationClock owns time and the simulator is
@@ -604,14 +484,6 @@ class Simulator:
         """One edge summary = the full-depth adapter set (every cell merges
         its members into one full LoRA tree before the backhaul hop)."""
         return lora_upload_bytes(self.cfg, self.cfg.n_layers)
-
-    def _resolved_buffer_k(self) -> int:
-        run = self.run
-        if run.agg.buffer_k is not None:
-            return run.agg.buffer_k
-        # buffered: semi-sync half-cohort; staleness: fully async (every
-        # upload commits, the discount keeps stale ones from dominating)
-        return 1 if run.agg.policy == "staleness" else max(1, self.u // 2)
 
     def _run_event(self, verbose: bool = False):
         run = self.run
@@ -636,7 +508,7 @@ class Simulator:
                            deadline=run.engine.deadline,
                            agg_policy=run.agg.policy,
                            agg_interval=run.agg.interval,
-                           buffer_k=self._resolved_buffer_k(),
+                           buffer_k=resolve_buffer_k(run.agg, self.u),
                            max_inflight_rounds=run.agg.max_inflight)
         agg_bytes_fn = None
         if run.agg.transport == "plane":
@@ -680,7 +552,8 @@ class Simulator:
             res = clock.run(on_serve=self._on_serve,
                             on_commit=lambda ev:
                                 self._commit_async(ev, verbose),
-                            on_round_start=self._on_round_start,
+                            on_round_start=lambda u, rnd, t:
+                                self.math.pull(u, rnd),
                             on_tick=tick)
             # final-state evaluation (the async analogue of the sync path's
             # last-round eval) — not for preempted runs, which are resumed
@@ -711,36 +584,10 @@ class Simulator:
             return False
         return True
 
-    def _on_round_start(self, u: int, rnd: int, t: float) -> None:
-        """A client pulls its model copy when it ENTERS a local round; the
-        lazily-executed math must use that copy, not whatever a later commit
-        redistributed mid-flight."""
-        self._round_pull[(u, rnd)] = (self.client_lora[u], self.client_opt[u],
-                                      self._client_version[u])
-
     def _on_serve(self, ev):
-        # run each client's round on the state it pulled at round start
-        swapped = {}
-        for u, r in zip(ev.uids, ev.rounds):
-            pull = self._round_pull.pop((u, r), None)
-            if pull is not None:
-                swapped[u] = (r, pull[2], self.client_lora[u],
-                              self.client_opt[u])
-                self.client_lora[u], self.client_opt[u] = pull[0], pull[1]
-        rounds = sorted(set(ev.rounds))
-        self._serve_round = (rounds[0] if len(rounds) == 1
-                             else " ".join(map(str, rounds)))
-        losses = [float(loss) for loss in self._serve_group(list(ev.uids))]
-        for u, (r, pull_version, cur_lora, cur_opt) in swapped.items():
-            if self._client_version[u] != pull_version:
-                # a commit refreshed u while this round was in flight: the
-                # stale local update loses the race — u continues from the
-                # redistributed global (its server-side half already serves
-                # from the post-commit state)
-                self.client_lora[u], self.client_opt[u] = cur_lora, cur_opt
-                self.discarded_updates.append((u, r))
-                if self.obs is not None and self.obs.metrics is not None:
-                    self.obs.metrics.inc("stale_discard")
+        self._serve_round = round_id(ev.rounds)
+        losses = self.math.serve_in_flight(ev.uids, ev.rounds,
+                                           self._serve_group)
         self._wave_losses.extend(losses)
         for u, r, ls in zip(ev.uids, ev.rounds, losses):
             self.loss_events.append((ev.end, u, r, ls))
@@ -792,57 +639,22 @@ class Simulator:
             return self._commit_sync_body(ev)
 
     def _commit_sync_body(self, ev) -> Union[float, Dict[int, float]]:
-        # each commit.* span holds one jitted program (core/aggregation.py)
-        w = agg_lib.commit_weights(self.data_sizes)
-        with span("commit.aggregate"):
-            if self._edges is not None:
-                # two-tier Eq. 6-8: edge cells partially merge their members,
-                # the cloud merges the edge summaries (telescopes to the flat
-                # weighted mean; edge partials kept for inspection/tests)
-                fulls = [lora_lib.assemble_full(
-                             self.client_lora[u],
-                             lora_lib.split_lora(self.server_lora[u],
-                                                 self.cuts[u])[1],
-                             self.cuts[u])
-                         for u in range(self.u)]
-                agg_full, self.edge_summaries, self.edge_masses = \
-                    agg_lib.hierarchical_aggregate(
-                        fulls, [float(s) for s in self.data_sizes],
-                        [list(cell) for cell in self._edges.cells])
-            else:
-                agg_full = agg_lib.commit_aggregate(
-                    self.client_lora, self.server_lora, w, tuple(self.cuts))
-        # the UPLOAD leg shipped the adapters the clients actually trained —
+        # the UPLOAD leg ships the adapters the clients actually trained —
         # price it at the PRE-migration cuts, before any decision applies
         up_old = max(self.link.transfer_s(lora_upload_bytes(self.cfg, cut))
                      for cut in self.cuts)
-        mig: Dict[int, float] = {}
-        changes: Dict[int, Tuple[int, int]] = {}
-        with span("commit.redistribute"):
-            if self._control is not None and ev is not None:
-                changes, mig = self._control.decide(ev.time,
-                                                    list(range(self.u)),
-                                                    ev.version)
-                if changes:
-                    self._apply_cut_changes(changes)
-            # re-split the aggregate at the (possibly new) cuts, once per
-            # cut: its clients share the arrays, which no step donates
-            cuts = tuple(sorted(set(self.cuts)))
-            at = [cuts.index(k) for k in self.cuts]
-            clients, servers = agg_lib.commit_redistribute(agg_full, cuts)
-            self.client_lora = [clients[i] for i in at]
-            self.server_lora = [servers[i] for i in at]
-        with span("commit.heads"):
-            # heads: dataset-weighted FedAvg
-            head = agg_lib.commit_heads(self.heads, w)
-            self.heads = [head] * self.u
-        self._global_full, self._global_head = agg_full, head
-        with span("commit.opt_reset"):
-            # optimizer states reset to match redistributed adapters
-            client_opts, server_opt = agg_lib.commit_opt_reset(
-                clients, servers[0], head, self.opt)
-            self.client_opt = [client_opts[i] for i in at]
-            self.server_opt = [server_opt] * self.u
+
+        def decide():
+            if self._control is None or ev is None:
+                return {}, {}
+            changes, mig = self._control.decide(ev.time, list(range(self.u)),
+                                                ev.version)
+            if changes:
+                self._apply_cut_changes(changes)
+            return changes, mig
+
+        changes, mig = self.math.commit_sync(self.data_sizes, self._edges,
+                                             decide)
         if self.run.agg.transport == "plane":
             if ev is not None:
                 # the clock ships the adapters through the plane (two-tier
@@ -893,25 +705,6 @@ class Simulator:
     def _commit_async_body(self, ev, verbose: bool) -> float:
         run = self.run
         contribs = list(ev.contributors)
-        alpha = 0.0
-        if run.agg.policy == "staleness":
-            alpha = 0.5 if run.agg.staleness_alpha is None else run.agg.staleness_alpha
-        w = [self.data_sizes[u] * agg_lib.staleness_discount(s, alpha)
-             for u, s in zip(contribs, ev.staleness)]
-        anchor = float(sum(self.data_sizes)
-                       - sum(self.data_sizes[u] for u in contribs))
-        with span("commit.aggregate"):
-            fulls = [lora_lib.assemble_full(
-                         self.client_lora[u],
-                         lora_lib.split_lora(self.server_lora[u], self.cuts[u])[1],
-                         self.cuts[u])
-                     for u in contribs]
-            self._global_full = agg_lib.merge_into_global(
-                self._global_full, fulls, w, anchor)
-        with span("commit.heads"):
-            self._global_head = agg_lib.aggregate_full_weighted(
-                [self._global_head] + [self.heads[u] for u in contribs],
-                [anchor] + w)
         # control decision: contributors stand at this commit boundary, but
         # only those with NO in-flight local round may migrate (an in-flight
         # round pulled client state shaped by the old cut).  The upload leg
@@ -919,28 +712,20 @@ class Simulator:
         up_old = max(self.link.transfer_s(lora_upload_bytes(self.cfg,
                                                             self.cuts[u]))
                      for u in contribs)
-        mig: Dict[int, float] = {}
-        changes: Dict[int, Tuple[int, int]] = {}
-        with span("commit.redistribute"):
-            if self._control is not None:
-                inflight = {u for (u, _r) in self._round_pull}
-                changes, mig = self._control.decide(
-                    ev.time, contribs, ev.version,
-                    eligible=[u for u in contribs if u not in inflight])
-                if changes:
-                    self._apply_cut_changes(changes)
-            for u in contribs:
-                c, s = lora_lib.split_lora(self._global_full, self.cuts[u])
-                self.client_lora[u] = c
-                self.server_lora[u] = lora_lib.embed_in_full_shape(
-                    s, self.lora_spec, self.cuts[u], "server")
-                self.heads[u] = self._global_head
-                self._client_version[u] += 1   # in-flight rounds of u now race
-        with span("commit.opt_reset"):
-            for u in contribs:
-                self.client_opt[u] = self.opt.init(self.client_lora[u])
-                self.server_opt[u] = self.opt.init(
-                    {"lora": self.server_lora[u], "head": self._global_head})
+
+        def decide():
+            if self._control is None:
+                return {}, {}
+            inflight = {u for (u, _r) in self.math.round_pull}
+            changes, mig = self._control.decide(
+                ev.time, contribs, ev.version,
+                eligible=[u for u in contribs if u not in inflight])
+            if changes:
+                self._apply_cut_changes(changes)
+            return changes, mig
+
+        changes, mig = self.math.commit_async(contribs, ev.staleness,
+                                              self.data_sizes, decide)
         if self.run.agg.transport == "plane":
             # the clock routes the adapter syncs; migrations ride as
             # per-client extras past each contributor's download
@@ -980,21 +765,14 @@ class Simulator:
     def _apply_cut_changes(self, changes: Dict[int, Tuple[int, int]]) -> None:
         """Real-math side of a cut migration (commit boundaries only): the
         live ``self.cuts`` entries are already updated by the control loop;
-        here the client's frozen prefix is re-sliced, jitted steps for the
-        new cut are ensured, and the analytic Eq. 10 terms are refreshed.
+        here the new cut's shared frozen prefix and jitted steps are
+        ensured, and the analytic Eq. 10 terms are refreshed.
         Adapters and optimizer states are NOT touched — the calling commit
         body redistributes them from the aggregated global at the new cut,
         which is exactly the same operation a commit performs anyway."""
         run = self.run
         for u, (_old, new) in changes.items():
-            pc = dict(self.params)
-            pc["layers"] = lora_lib.slice_stack(self.params["layers"], 0, new)
-            self.client_params[u] = pc
-            if new not in self._srv_steps:
-                self._srv_steps[new] = splitfl.make_server_step_cls(
-                    self.model, self.opt, path="sliced", static_cut=new)
-                self._cli_steps[new] = splitfl.make_client_step(
-                    self.model, self.opt, new, path="sliced")
+            self.math.at_cut(new)
             self.times[u] = client_step_times(
                 self.cfg, new, self.devices[u], self.server_dev,
                 LinkProfile(self.network.nominal_mbps(u)),
@@ -1004,54 +782,16 @@ class Simulator:
 
     def _maybe_eval(self, rnd: int, rec: RoundRecord, verbose: bool) -> bool:
         """Shared per-round eval/early-stop; True means stop training."""
-        run = self.run
-        if (rnd + 1) % run.eval_every == 0 or rnd == run.rounds - 1:
-            rec.accuracy, rec.f1 = self.evaluate()
-            if verbose:
-                print(f"[{run.scheme}/{run.engine.scheduler}] round {rnd+1:4d} "
-                      f"t={rec.sim_time_s:9.1f}s loss={rec.mean_loss:.4f} "
-                      f"acc={rec.accuracy:.4f} f1={rec.f1:.4f}")
-            if (run.target_accuracy is not None
-                    and rec.accuracy >= run.target_accuracy):
-                return True
-        return False
+        return self.math.maybe_eval(
+            rnd, rec, self.data_sizes, verbose,
+            f"{self.run.scheme}/{self.run.engine.scheduler}")
 
     # ------------------------------------------------------------------ eval
     def evaluate(self, max_batches: int = 32):
         """Global model = aggregate of current full adapters (ours/sfl), the
         traveling set (sl), or the standing async global (buffered/staleness
         policies); evaluated centrally on the held-out set."""
-        if self.run.agg.policy != "sync":
-            full = self._global_full
-            head = self._global_head
-        elif self.run.scheme == "sl":
-            full = self.server_lora[0]
-            head = self.heads[0]
-        else:
-            fulls = [lora_lib.assemble_full(self.client_lora[u],
-                                            lora_lib.split_lora(self.server_lora[u], self.cuts[u])[1],
-                                            self.cuts[u])
-                     for u in range(self.u)]
-            full = agg_lib.aggregate_full(fulls, self.data_sizes)
-            w = np.array(self.data_sizes, np.float64)
-            w /= w.sum()
-            head = jax.tree.map(lambda *hs: sum(float(wi) * h for wi, h in zip(w, hs)),
-                                *self.heads)
-        params = dict(self.params)
-        params["cls_head"] = head
-
-        preds, golds = [], []
-        loader = ClassificationLoader(self.test, self.run.batch_size, seed=0)
-        for i, batch in enumerate(loader.all_batches()):
-            if i >= max_batches:
-                break
-            logits = self._eval_logits(
-                params, full, {k: jnp.asarray(v) for k, v in batch.items()})
-            preds.append(np.argmax(np.asarray(logits), -1))
-            golds.append(batch["label"])
-        pred = np.concatenate(preds)
-        gold = np.concatenate(golds)
-        return M.accuracy(pred, gold), M.macro_f1(pred, gold)
+        return self.math.evaluate(self.data_sizes, max_batches)
 
     # ------------------------------------------------------------------ driver
     def run_training(self, verbose: bool = False):
@@ -1104,7 +844,7 @@ class Simulator:
             "history": [[r.round, r.sim_time_s, r.mean_loss, r.accuracy,
                          r.f1] for r in self.history],
             "wave_losses": list(self._wave_losses),
-            "discarded": [list(d) for d in self.discarded_updates],
+            "discarded": [list(d) for d in self.math.discarded_updates],
             "obs": (self.obs.state_dict() if self.obs is not None else None),
         }
 
@@ -1130,23 +870,24 @@ class Simulator:
             "server_opt": [tuple(o) for o in self.server_opt],
             "loader_state": np.asarray([ld.state() for ld in self.loaders],
                                        np.int64),
-            "global_full": self._global_full,
-            "global_head": self._global_head,
+            "global_full": self.store.global_full,
+            "global_head": self.store.global_head,
             "loss_events": (np.asarray(self.loss_events, np.float64)
                             if self.loss_events
                             else np.zeros((0, 4), np.float64)),
             "des": pack_json(self._des_state()),
-            "client_version": np.asarray(self._client_version, np.int64),
+            "client_version": np.asarray(
+                [self.math.version.get(u, 0) for u in range(self.u)],
+                np.int64),
             # in-flight round pulls: the client-side state each live
             # (uid, round) snapshot at round start — pytrees, so they ride
             # the array checkpoint next to the adapters
             "round_pull": {
                 f"{u}:{r}": {"lora": lora, "opt": tuple(opt),
                              "ver": np.int64(ver)}
-                for (u, r), (lora, opt, ver) in self._round_pull.items()},
+                for (u, r), (lora, opt, ver) in self.math.round_pull.items()},
             "ef_residual": {str(u): arr
-                            for u, arr in enumerate(self._ef_residual)
-                            if arr is not None},
+                            for u, arr in sorted(self.math.ef_residual.items())},
         }
         return st
 
@@ -1166,17 +907,19 @@ class Simulator:
                     # RESTORED cuts, not the setup-phase ratios
                     refresh_priorities(self._control.pri, self.cuts,
                                        [d.tflops for d in self.devices])
-        self.client_lora = list(st["client_lora"])
-        self.server_lora = list(st["server_lora"])
-        self.heads = list(st["heads"])
-        self.client_opt = [AdamWState(*o) for o in st["client_opt"]]
-        self.server_opt = [AdamWState(*o) for o in st["server_opt"]]
+        if "global_full" in st:   # async-engine state (absent in old saves)
+            self.store.set_global(st["global_full"], st["global_head"])
+        for u in range(self.u):
+            slot = self.store.slot(u)
+            slot["client_lora"] = st["client_lora"][u]
+            slot["server_lora"] = st["server_lora"][u]
+            slot["head"] = st["heads"][u]
+            slot["client_opt"] = AdamWState(*st["client_opt"][u])
+            slot["server_opt"] = AdamWState(*st["server_opt"][u])
         if "loader_state" in st:
             for ld, s in zip(self.loaders, np.asarray(st["loader_state"])):
                 ld.restore(s)
-        if "global_full" in st:   # async-engine state (absent in old saves)
-            self._global_full = st["global_full"]
-            self._global_head = st["global_head"]
+        if "loss_events" in st:
             self.loss_events = [(float(t), int(u), int(r), float(ls))
                                 for t, u, r, ls in np.asarray(st["loss_events"])]
         # ---- mid-flight state (snapshot schema >= 2; docs/checkpointing.md)
@@ -1197,7 +940,8 @@ class Simulator:
                             None if f1 is None else float(f1))
                 for r, t, l, a, f1 in des["history"]]
             self._wave_losses = [float(x) for x in des["wave_losses"]]
-            self.discarded_updates = [tuple(d) for d in des["discarded"]]
+            self.math.discarded_updates[:] = [tuple(d)
+                                              for d in des["discarded"]]
             if des.get("obs") is not None and self.obs is not None:
                 # snapshots written without obs (or loaded into a run that
                 # turned it off) skip this: obs never gates a resume
@@ -1206,15 +950,16 @@ class Simulator:
             # waits here until then
             self._pending_clock_state = des["clock"]
         if "client_version" in st:
-            self._client_version = [int(v)
-                                    for v in np.asarray(st["client_version"])]
-        self._round_pull = {}
+            self.math.version = {
+                u: int(v) for u, v in enumerate(np.asarray(st["client_version"]))}
+        self.math.round_pull = {}
         for key, rec in (st.get("round_pull") or {}).items():
             u, r = (int(x) for x in key.split(":"))
-            self._round_pull[(u, r)] = (rec["lora"], AdamWState(*rec["opt"]),
-                                        int(np.asarray(rec["ver"])))
+            self.math.round_pull[(u, r)] = (rec["lora"],
+                                            AdamWState(*rec["opt"]),
+                                            int(np.asarray(rec["ver"])))
         for u_str, arr in (st.get("ef_residual") or {}).items():
-            self._ef_residual[int(u_str)] = arr
+            self.math.ef_residual[int(u_str)] = arr
         return int(st["round"])
 
     def resume(self, path: str) -> int:
@@ -1290,7 +1035,8 @@ def run_federated_training(cfg: ModelConfig, fleet_spec, run: FedRunConfig,
 
     ``fleet_spec`` is a ``FleetSpec``; returns the driver object after
     training — ``Simulator`` or ``PopulationTrainer``, both carrying
-    ``history`` / ``loss_events`` / ``evaluate()``.
+    ``history`` / ``loss_events`` and a ``math`` whose ``evaluate`` scores
+    the global model.
     """
     if fleet_spec.n < run.fleet.population_threshold:
         sim = Simulator(cfg, fleet=fleet_spec, train=train, test=test,

@@ -2,9 +2,10 @@
 
 The tracer of ``repro.obs.tracer`` records the modelled phones, links and
 server slots in *simulated* seconds.  The spans here record what the host
-really does on the real-math federated path (``fed/simulator.py``): the
-round, each dispatch group's serve and each commit, with their parts.  They
-are ``jax.profiler.TraceAnnotation`` spans, so they land in a profiler trace
+really does on the real-math federated path (``fed/simulator.py``,
+``fed/split_math.py``): the round, each dispatch group's serve and each
+commit, with their parts.  They are ``jax.profiler.TraceAnnotation``
+spans, so they land in a profiler trace
 beside the device's operations, on one clock, and are recorded exactly when
 a profiler trace is running; otherwise a span costs one constructor call.
 There is no option: the two clocks are never mixed in one export.
@@ -25,7 +26,7 @@ __all__ = ["HOST_SPANS", "span"]
 # the taxonomy: every name ``span`` is called with, parents before children
 HOST_SPANS = (
     "fed.round",            # Simulator.run_round
-    "fed.serve",            # one dispatch group of _serve_group
+    "fed.serve",            # one dispatch group of SplitMath.serve
     "fed.client_fwd",       # batch draw + upload + client forward (+ int8 uplink)
     "fed.server_step",      # dispatch of the server step
     "fed.loss_read",        # starts the copy of the client's loss to the host;

@@ -57,11 +57,12 @@ def _sim(setup):
             out.append(jax.random.normal(sub, leaf.shape, leaf.dtype))
         return jax.tree.unflatten(treedef, out)
 
-    sim.client_lora = [noise(c) for c in sim.client_lora]
-    sim.server_lora = [noise(s) for s in sim.server_lora]
-    sim.heads = [noise(h) for h in sim.heads]
-    sim.client_opt = [o._replace(mu=noise(o.mu)) for o in sim.client_opt]
-    sim.server_opt = [o._replace(mu=noise(o.mu)) for o in sim.server_opt]
+    for u in range(sim.u):
+        slot = sim.store.slot(u)
+        for name in ("client_lora", "server_lora", "head"):
+            slot[name] = noise(slot[name])
+        for name in ("client_opt", "server_opt"):
+            slot[name] = slot[name]._replace(mu=noise(slot[name].mu))
     return sim
 
 
@@ -74,7 +75,7 @@ def _eager(sim, new_cuts):
                                            sim.cuts, sim.data_sizes)
     parts = [lora_lib.split_lora(agg_full, k) for k in new_cuts]
     clients = [c for c, _ in parts]
-    servers = [lora_lib.embed_in_full_shape(s, sim.lora_spec, k, "server")
+    servers = [lora_lib.embed_in_full_shape(s, sim.math.lora_spec, k, "server")
                for (_, s), k in zip(parts, new_cuts)]
     w = np.array(sim.data_sizes, np.float64)
     w /= w.sum()
@@ -149,9 +150,10 @@ def test_a_cut_migration_resplits_at_the_new_cut(setup):
     charge = sim._commit_sync(types.SimpleNamespace(time=0.0, version=1))
     assert sim.cuts == new_cuts
     assert set(charge) == set(range(sim.u)) and charge[0] > charge[1]
-    assert 3 in sim._srv_steps
+    assert 3 in sim.math.per_cut
     _check(sim, want)
-    assert jax.tree.leaves(sim.client_params[0]["layers"])[0].shape[0] == 3
+    prefix = sim.math.at_cut(sim.cuts[0]).prefix
+    assert jax.tree.leaves(prefix["layers"])[0].shape[0] == 3
 
 
 def test_a_commit_is_four_dispatches(setup, tmp_path):

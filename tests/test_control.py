@@ -451,12 +451,12 @@ def test_simulator_reactive_end_to_end_real_math(sim_setup):
         if ev.applied:
             for u, (_old, new) in ev.cut_changes.items():
                 assert sim.cuts[u] in range(1, sim.cfg.n_layers)
-                assert new in sim._cli_steps
-    # adapters and client params stay shape-consistent with the live cuts
-    from repro.core import lora as lora_lib
+                assert new in sim.math.per_cut
+    # adapters and client prefixes stay shape-consistent with the live cuts
     for u in range(4):
-        n_l = jax_leading_dim(sim.client_params[u]["layers"])
-        assert n_l == sim.cuts[u]
+        prefix = sim.math.at_cut(sim.cuts[u]).prefix
+        assert jax_leading_dim(prefix["layers"]) == sim.cuts[u]
+        assert jax_leading_dim(sim.client_lora[u]) == sim.cuts[u]
 
 
 def jax_leading_dim(tree):
@@ -491,4 +491,5 @@ def test_simulator_state_dict_roundtrips_cuts(sim_setup):
     fresh.load_state_dict(st)
     assert fresh.cuts == sim.cuts
     for u in range(4):
-        assert jax_leading_dim(fresh.client_params[u]["layers"]) == sim.cuts[u]
+        assert jax_leading_dim(fresh.client_lora[u]) == sim.cuts[u]
+        assert sim.cuts[u] in fresh.math.per_cut

@@ -105,8 +105,11 @@ def _watch(sim, log):
             return (_Watched(loss, log), *rest)
         return call
 
-    sim._srv_steps = {c: watched(f) for c, f in sim._srv_steps.items()}
-    sim._srv_step_batched = watched(sim._srv_step_batched)
+    math = sim.math
+    for c, steps in list(math.per_cut.items()):
+        math.per_cut[c] = steps._replace(
+            server_step=watched(steps.server_step))
+    math._srv_step_batched = watched(math._srv_step_batched)
     serve, commit = sim._serve_group, sim._commit_sync
 
     def serving(grp):
@@ -122,11 +125,37 @@ def _watch(sim, log):
     sim._serve_group, sim._commit_sync = serving, committing
 
 
-@pytest.mark.parametrize("chunk", [1, 3])
-def test_serve_returns_unread_device_scalars(setup, chunk):
+def _simulator_serves(setup, chunk):
     sim = _sim(setup, cohort_chunk=chunk)
     served = _served(sim)
     sim.run_round(0)
+    return served
+
+
+def _trainer_serves(setup, chunk):
+    """The population trainer's serve (``SplitMath.serve``) on the same
+    three-client fleet, one round's groups."""
+    from repro.fed.fleet import FleetSpec
+    from repro.fed.population_training import PopulationTrainer
+    cfg, train, test = setup
+    run = FedRunConfig(scheme="ours", rounds=1, batch_size=4, seq_len=16,
+                       lr=3e-3, eval_every=100, seed=5,
+                       engine=EngineConfig(mode="event", cohort_chunk=chunk))
+    tr = PopulationTrainer(
+        cfg, FleetSpec(n=3, seed=5, link_model="constant").population(),
+        run, train, test)
+    groups = [[0], [1], [2]] if chunk == 1 else [[0, 1, 2]]
+    return [list(zip(g, tr.math.serve(g, tr._loader, 0))) for g in groups]
+
+
+@pytest.mark.parametrize("driver,chunk", [
+    pytest.param("simulator", 1, id="1"),
+    pytest.param("simulator", 3, id="3"),
+    pytest.param("trainer", 1, id="trainer-1"),
+    pytest.param("trainer", 3, id="trainer-3")])
+def test_serve_returns_unread_device_scalars(setup, driver, chunk):
+    serves = {"simulator": _simulator_serves, "trainer": _trainer_serves}
+    served = serves[driver](setup, chunk)
     assert [len(g) for g in served] == ([1, 1, 1] if chunk == 1 else [3])
     for grp in served:
         for _, loss in grp:
